@@ -78,8 +78,9 @@ def main() -> None:
             "The distributed sweep (shards × x-strategy × B over "
             "prepare(A, mesh=...)) lives in benchmarks/distributed.py — it "
             "must run as its own process to force a multi-device host "
-            "platform.  Docs: docs/architecture.md, docs/formats.md, "
-            "docs/tuning.md, docs/distributed.md."
+            "platform.  Every section runs in this one process.  Docs: "
+            "docs/architecture.md, docs/formats.md, docs/tuning.md, "
+            "docs/distributed.md."
         ),
     )
     ap.add_argument("--quick", action="store_true", help="smaller matrices")
@@ -90,6 +91,9 @@ def main() -> None:
                     help="also write per-section rows as JSON records "
                          '({"section", "name", "value", "unit"})')
     args = ap.parse_args()
+    from repro.util.platform import configure_compile_cache
+
+    configure_compile_cache()
     scale = 1024 if args.quick else 2048
     only = set(args.only.split(",")) if args.only else None
     records = []

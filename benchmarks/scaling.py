@@ -1,55 +1,49 @@
 """Paper Fig. 10 analogue: scalability study.
 
 The paper scales OpenMP threads on Rome/Ice Lake; the JAX analogue scales
-device count for the distributed SpMV inside a CG solve.  Runs in a
-subprocess per device count (device count is locked at first jax init).
+device count for the distributed SpMV.  Everything runs in this process over
+meshes built from the first d of ``jax.devices()`` (d = 1, 2, 4, 8, up to
+the devices present) — no child process, so nothing competes for a chip this
+process already holds.  On a CPU host, present several devices by setting
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before starting.
 Speedups are normalised to 1 device, geometric-mean across the suite subset.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_BODY = r"""
-import os, sys, json, time
-os.environ['XLA_FLAGS'] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import numpy as np, jax, jax.numpy as jnp
-from repro.core.distributed import shard_csr, dist_spmv_halo
-from repro.core.ordering import bandk
+from benchmarks.common import emit, time_fn
 from repro.configs.spmv_suite import SUITE
-from repro.launch.mesh import make_host_mesh
-from benchmarks.common import time_fn
-
-D = int(sys.argv[1])
-mesh = make_host_mesh()
-out = {}
-for entry in SUITE:
-    if entry.id not in (6, 8, 11):
-        continue
-    A = entry.build(128)
-    A = A.symmetric_permute(bandk(A))
-    S = shard_csr(A, mesh.shape['data'])
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(A.m), jnp.float32)
-    t = time_fn(lambda v: dist_spmv_halo(S, v, mesh), x, warmup=3, iters=10)
-    out[entry.name] = t
-print(json.dumps(out))
-"""
+from repro.core.distributed import dist_spmv_halo, shard_csr
+from repro.core.ordering import bandk
 
 
-def run(device_counts=(1, 2, 4, 8)) -> list:
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src") + ":" + REPO)
+def run(device_counts=None) -> list:
+    devices = jax.devices()
+    if device_counts is None:
+        device_counts = [d for d in (1, 2, 4, 8) if d <= len(devices)]
+    mats = {}
+    for entry in SUITE:
+        if entry.id in (6, 8, 11):
+            A = entry.build(128)
+            mats[entry.name] = A.symmetric_permute(bandk(A))
+    rng = np.random.default_rng(0)
+    xs = {k: jnp.asarray(rng.standard_normal(A.m), jnp.float32)
+          for k, A in mats.items()}
+
     times = {}
     for d in device_counts:
-        res = subprocess.run(
-            [sys.executable, "-c", _BODY, str(d)],
-            capture_output=True, text=True, timeout=560, env=env,
-        )
-        assert res.returncode == 0, res.stderr
-        times[d] = json.loads(res.stdout.strip().splitlines()[-1])
+        mesh = Mesh(np.asarray(devices[:d]).reshape(d, 1), ("data", "model"))
+        times[d] = {}
+        for name, A in mats.items():
+            S = shard_csr(A, d)
+            times[d][name] = time_fn(
+                lambda v: dist_spmv_halo(S, v, mesh), xs[name],
+                warmup=3, iters=10,
+            )
 
     rows = []
     base = times[device_counts[0]]
@@ -57,12 +51,9 @@ def run(device_counts=(1, 2, 4, 8)) -> list:
         speedups = [base[k] / times[d][k] for k in base]
         geo = float(np.exp(np.mean(np.log(speedups))))
         rows.append({"devices": d, "geomean_speedup": round(geo, 3)})
-    from benchmarks.common import emit
     emit(rows, ["devices", "geomean_speedup"])
     return rows
 
-
-import numpy as np
 
 if __name__ == "__main__":
     run()

@@ -39,7 +39,7 @@ from benchmarks.format_select import powerlaw
 from repro.configs.spmv_suite import grid_laplacian_2d
 from repro.serve import ServeEngine
 
-PREPARE_OPTS = dict(device="tpu_v5e", format="auto", interpret=True)
+PREPARE_OPTS = dict(device="tpu_v5e", format="auto")
 
 
 class _ArrivalClock:

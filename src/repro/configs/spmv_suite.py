@@ -18,7 +18,8 @@ families that deliberately defeat the row-balanced formats (power-law hub
 rows with empty rows; a mostly-diagonal stencil with a low-occupancy
 fringe).  They are intentionally *not* part of :data:`SUITE` — the suite's
 routing decisions are pinned by tests — and load via
-:func:`load_adversarial`.
+:func:`load_adversarial`.  :func:`pareto_rows` is the milder irregular
+family the SELL-C-σ backend is for: a bounded Pareto tail of row lengths.
 """
 from __future__ import annotations
 
@@ -261,6 +262,36 @@ def powerlaw_zipf(
             jnp.asarray(
                 rng.standard_normal(len(idx)).astype(np.float32), jnp.float32
             ),
+            (n, n),
+        )
+    )
+
+
+def pareto_rows(
+    n: int,
+    seed: int = 19,
+    alpha: float = 2.0,
+    scale: float = 4.0,
+    max_len: int = 64,
+) -> CSRMatrix:
+    """Pareto-distributed row lengths, capped (irregular FEM/graph family).
+
+    Row lengths are ``1 + ⌊scale · Lomax(alpha)⌋`` capped at ``max_len``,
+    with uniformly random columns: irregular enough that ``row_var`` leaves
+    the regular bound, yet the cap keeps ``row_skew`` below the segsum
+    threshold — the matrices SELL-C-σ's σ-sorted chunks are built for.
+    """
+    rng = np.random.default_rng(seed)
+    lengths = np.minimum(1 + (rng.pareto(alpha, n) * scale).astype(np.int64), max_len)
+    rows = np.repeat(np.arange(n), lengths)
+    cols = rng.integers(0, n, rows.shape[0])
+    key = rows.astype(np.int64) * n + cols
+    _, idx = np.unique(key, return_index=True)
+    return csr_from_coo(
+        COOMatrix(
+            jnp.asarray(rows[idx], jnp.int32),
+            jnp.asarray(cols[idx], jnp.int32),
+            jnp.asarray(rng.standard_normal(len(idx)).astype(np.float32)),
             (n, n),
         )
     )

@@ -58,13 +58,13 @@ inputs, all three x strategies, and overlapped-vs-blocking execution.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.formats import CSRMatrix
 from repro.kernels.ops import _pad_rows, combine_tile_rows
@@ -280,10 +280,10 @@ def _csr_plan_shard_map(plan: ShardPlan, mesh: Mesh, axis: str):
         return _local_spmv(rp[0], ci[0], vl[0], x_full)
 
     x_spec = P() if strategy == "replicated" else P(axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), x_spec),
-        out_specs=P(axis), check_rep=False,
+        out_specs=P(axis), check_vma=False,
     )
 
 
@@ -590,13 +590,22 @@ class ShardedPreparedSpMV:
         return self.plan.collective_bytes(B, itemsize)
 
     # -- execution -----------------------------------------------------------
-    def __call__(self, x: jax.Array) -> jax.Array:
-        """Sharded SpMV / SpMM in the reordered index space ([n] or [n, B])."""
+    def _executor(self):
         fn = self._call_cache.get("call")
         if fn is None:
             fn = _build_plan_call(self)
             self._call_cache["call"] = fn
-        return fn(x)
+        return fn
+
+    def __call__(self, x: jax.Array) -> jax.Array:
+        """Sharded SpMV / SpMM in the reordered index space ([n] or [n, B])."""
+        return self._executor()(x)
+
+    def lower(self, x: jax.Array):
+        """The jitted sharded program for x's shape (``jax.stages.Lowered``),
+        with the per-shard stacks as arguments rather than constants."""
+        fn = self._executor()
+        return fn.func.lower(*fn.args, x)
 
     def matmat(self, X: jax.Array) -> jax.Array:
         """Explicit multi-vector alias: Y = A X for X of shape [n, B]."""
@@ -750,10 +759,10 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                     xp, a["scale"][0] if has_scale else None,
                 )
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis),) * len(names) + (x_spec,),
-            out_specs=P(axis), check_rep=False,
+            out_specs=P(axis), check_vma=False,
         )
         arg_arrays = tuple(arrs[k] for k in names)
         rem = tiles.remainder_nnz
@@ -770,8 +779,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                 y = y.at[rem_row].add(rv * x[rem_col].astype(y.dtype))
             return y
 
-        jitted = jax.jit(call)
-        return lambda x: jitted(*arg_arrays, x)
+        return functools.partial(jax.jit(call), *arg_arrays)
 
     if base.backend == "sellcs":
         from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
@@ -827,10 +835,10 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                     a["scale"][0] if has_scale else None,
                 )
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis),) * len(names) + (x_spec,),
-            out_specs=P(axis), check_rep=False,
+            out_specs=P(axis), check_vma=False,
         )
         arg_arrays = tuple(arrs[k] for k in names)
 
@@ -841,8 +849,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
             out = jnp.zeros((m + 1,) + y_sorted.shape[1:], y_sorted.dtype)
             return out.at[row_perm].set(y_sorted)[:m]
 
-        jitted = jax.jit(call)
-        return lambda x: jitted(*arg_arrays, x)
+        return functools.partial(jax.jit(call), *arg_arrays)
 
     # CSR-2 / CPU fallback: pure-jnp oracle inside shard_map (no tile view) —
     # the same plan executor the legacy dist_spmv_* shims use.
@@ -853,8 +860,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
         xin = x if strategy == "replicated" else _pad_rows(x, D * Rs)
         return f(rp, ci, vl, xin)[:m]
 
-    jitted = jax.jit(call)
-    return lambda x: jitted(S.row_ptr, S.col_idx, S.vals, x)
+    return functools.partial(jax.jit(call), S.row_ptr, S.col_idx, S.vals)
 
 
 def shard_prepared(
@@ -879,7 +885,9 @@ def shard_prepared(
     the CSR-2 raw-row fallback and execute per-shard through the segment-sum
     oracle inside ``shard_map``.  The decline is observable — a
     ``distributed/tile_decline.<backend>`` counter fires and the per-shard
-    registry decisions are still recorded in ``shard_backends``.
+    registry decisions are still recorded in ``shard_backends``.  A
+    compiled (TPU) segsum or diahybrid operator refuses instead: there the
+    decline would swap its kernel for the oracle.
 
     On top of the partition, a :class:`ShardPlan` is built: per-tile column
     reach classifies each shard's tiles as interior or boundary, the halo
@@ -940,6 +948,12 @@ def shard_prepared(
         # CSR-2 fallback: no tile view — raw row partitioning + oracle.
         # segsum/diahybrid land here (their containers are not row-block
         # shardable), as does CSR-k prepared without tiles (cpu devices).
+        # On a TPU that would replace their kernels by the oracle: refuse.
+        if base.backend in ("segsum", "diahybrid") and not base.interpret:
+            raise ValueError(
+                f"the {base.backend} backend has no sharded kernel path; "
+                "prepare it without mesh= on a TPU"
+            )
         if A is not None:
             src = A
         elif base.csrk is not None:
@@ -1097,6 +1111,11 @@ def shard_prepared(
             arrs["cols"] = _stack_shards(c, D, Tp)
             if scale is not None:
                 arrs["scale"] = _stack_shards(scale, D, Tp)
+
+    # each stack [D, ...] lives one shard per device, so every shard's tiles
+    # are resident where its kernel runs (no per-call reshard)
+    placed = NamedSharding(mesh, P(axis))
+    arrs = {k: jax.device_put(v, placed) for k, v in arrs.items()}
 
     # -- telemetry: the sharding decisions, as metrics rather than only as
     # operator attributes (docs/observability.md) ---------------------------

@@ -20,6 +20,7 @@ DIA + CSR-remainder hybrid (Fukaya et al.).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -51,6 +52,7 @@ from repro.sparse import (
 )
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels.gather import WHOLE_X_MAX_COLS, resolve_interpret
 from repro.obs import annotate, get_registry
 
 
@@ -80,7 +82,7 @@ class PreparedSpMV:
     params: tuner_mod.TuningParams
     device: str
     gather_mode: str = "onehot"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     backend: str = "csrk"
     sell: Optional[SELLCSMatrix] = None
     sell_tiles: Optional[SELLCSTiles] = None
@@ -147,37 +149,38 @@ class PreparedSpMV:
             return Y[:, :B]
         return self._dispatch(x)
 
-    def _dispatch(self, x: jax.Array) -> jax.Array:
-        """Backend kernel launch at x's natural width (no fixed-width pad)."""
+    def launch(self):
+        """The backend's kernel launch as ``(fn, operands)``.
+
+        ``fn(operands, x)`` is a natural-width call (no ``spmm_width`` pad).
+        ``operands`` is a pytree of the operator's device arrays, so
+        ``jax.jit(fn).lower(operands, x)`` lowers the launch with those
+        arrays as arguments rather than as baked-in constants.
+        """
         chunk = self.params.gather_chunk
+        tiled = dict(gather_mode=self.gather_mode, gather_chunk=chunk,
+                     interpret=self.interpret)
         if self.backend == "sellcs":
-            return kops.spmv_sellcs(
-                self.sell_tiles, x, gather_mode=self.gather_mode,
-                gather_chunk=chunk, interpret=self.interpret,
-            )
+            return functools.partial(kops.spmv_sellcs, **tiled), self.sell_tiles
         if self.backend == "segsum":
-            return kops.spmv_segsum(
-                self.segsum, x, gather_mode=self.gather_mode,
-                gather_chunk=chunk, interpret=self.interpret,
-            )
+            return functools.partial(kops.spmv_segsum, **tiled), self.segsum
         if self.backend == "diahybrid":
-            return kops.spmv_diahybrid(self.dia, x, interpret=self.interpret)
+            return (functools.partial(kops.spmv_diahybrid, interpret=self.interpret),
+                    self.dia)
         if self.tile_buckets is not None:
-            return kops.spmv_csrk_bucketed(
-                self.tile_buckets, x, gather_mode=self.gather_mode,
-                gather_chunk=chunk, interpret=self.interpret,
-            )
+            return (functools.partial(kops.spmv_csrk_bucketed, **tiled),
+                    self.tile_buckets)
         if self.tiles is not None:
-            return kops.spmv_csrk(
-                self.tiles, x, gather_mode=self.gather_mode,
-                gather_chunk=chunk, interpret=self.interpret,
-            )
+            return functools.partial(kops.spmv_csrk, **tiled), self.tiles
         # CPU path (CSR-2): hierarchy collapses to the segmented CSR kernel;
         # super-rows drive the parallel partitioning, which XLA:CPU derives
         # from the segment structure.
-        if x.ndim == 2:
-            return kref.spmm_csr(self.csr, x)
-        return kref.spmv_csr(self.csr, x)
+        return _csr_product, self.csr
+
+    def _dispatch(self, x: jax.Array) -> jax.Array:
+        """Backend kernel launch at x's natural width (no fixed-width pad)."""
+        fn, operands = self.launch()
+        return fn(operands, x)
 
     def matmat(self, X: jax.Array) -> jax.Array:
         """Explicit multi-vector alias: Y = A X for X of shape [n, B]."""
@@ -257,6 +260,10 @@ class PreparedSpMV:
         ))
         return sum(int(leaf.nbytes) for leaf in leaves
                    if hasattr(leaf, "nbytes"))
+
+
+def _csr_product(csr: CSRMatrix, x: jax.Array) -> jax.Array:
+    return kref.spmm_csr(csr, x) if x.ndim == 2 else kref.spmv_csr(csr, x)
 
 
 def _record_prepared(op: PreparedSpMV) -> PreparedSpMV:
@@ -368,7 +375,7 @@ def prepare(
     params: tuner_mod.TuningParams | None = None,
     gather_mode: str = "onehot",
     gather_chunk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     adaptive: bool = False,
     sell_c: int = 8,
     sell_sigma: int | None = None,
@@ -418,10 +425,14 @@ def prepare(
       params: explicit :class:`~repro.core.tuner.TuningParams`; None runs the
         constant-time tuner.
       gather_mode: in-kernel x-gather ("onehot" MXU matmuls | "take").
+        "take" has no Mosaic lowering: it runs in interpret mode only, and
+        a TPU prepare refuses it.
       gather_chunk: one-hot gather chunk width (a 128 multiple).  None defers
         to the tuner (``TuningParams.gather_chunk``, which the fitted device
         model can set); an explicit value overrides both.
-      interpret: run Pallas in interpret mode (True off-TPU).
+      interpret: Pallas execution mode.  None (default) follows the
+        platform: the CPU interprets the kernels, a TPU compiles them, any
+        other platform raises ``ValueError``.
       adaptive: replace the paper's rdensity-only formula with the
         variance-aware bytes-model tuner (beyond-paper; CSR-k path only).
       sell_c / sell_sigma: SELL-C-σ chunk height and sorting window
@@ -470,7 +481,19 @@ def prepare(
       is given) whose ``__call__`` maps x of shape [n] or [n, B] to y of
       shape [m] resp. [m, B] in the reordered index space;
       ``apply_original`` works in the matrix's original index space.
+
+    Raises:
+      ValueError: for an unknown option, ``gather_mode="take"`` on a TPU,
+        or a SELL-C-σ / segsum / diahybrid matrix wider than its kernel's
+        whole-x limit (:data:`repro.kernels.gather.WHOLE_X_MAX_COLS`).
     """
+    interpret = resolve_interpret(interpret)
+    if gather_mode not in ("onehot", "take"):
+        raise ValueError(f"unknown gather_mode {gather_mode!r} (expected onehot|take)")
+    if gather_mode == "take" and not interpret:
+        raise ValueError(
+            'gather_mode="take" has no Mosaic lowering; on a TPU use "onehot"'
+        )
     if mesh is not None:
         # The sharded operator partitions the *monolithic* tile view (whole
         # tiles per shard), so the bucketed layout is not built here.
@@ -504,6 +527,14 @@ def prepare(
         with reg.timer("prepare", "phase.stats"):
             stats = compute_stats(A)
             format = select_format(stats, device)
+    if format in WHOLE_X_MAX_COLS:
+        cols = max(A.shape) if format == "diahybrid" else A.shape[1]
+        if cols > WHOLE_X_MAX_COLS[format]:
+            raise ValueError(
+                f"the {format} kernel holds x whole in VMEM and addresses at "
+                f"most {WHOLE_X_MAX_COLS[format]} columns; this matrix needs "
+                f"{cols} (shape {A.shape})"
+            )
     if value_dtype == "auto":
         with reg.timer("prepare", "phase.value_dtype"):
             # the diahybrid plane has no slot grouping → no int8 scales

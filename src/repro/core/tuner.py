@@ -101,13 +101,19 @@ def load_fitted_device_model(
 
     The file maps device name → ``{"ssrs": [a, b], "srs": [a, b],
     "gather_chunk": g}``.  A missing/unreadable file or absent device entry
-    falls back to the hand-set model in :data:`DEVICES` — the measured model
-    is an accelerant, never a requirement (paper Sec. 4's portability).
+    falls back to the hand-set model for ``name`` in :data:`DEVICES` — the
+    measured model is an accelerant, never a requirement (paper Sec. 4's
+    portability).  A ``name`` with no hand-set model raises ``ValueError``:
+    there is nothing honest to fall back to.
     """
     import json
     import os
 
-    fallback = DEVICES.get(name, TPU_V5E)
+    if name not in DEVICES:
+        raise ValueError(
+            f"no device model named {name!r} (known: {', '.join(DEVICES)})"
+        )
+    fallback = DEVICES[name]
     if not path or not os.path.exists(path):
         return fallback
     try:

@@ -1,76 +1,268 @@
-"""Shared one-hot gather for the Pallas SpMV kernels.
+"""Shared in-kernel building blocks of the Pallas SpMV kernels.
 
-Both the CSR-k and SELL-C-σ kernels express x[col_idx] as chunked one-hot
-matmuls so the gather runs on the MXU — SpMV is bandwidth-bound, so spending
-idle MXU FLOPs to avoid scattered memory access is the right trade on TPU.
-This module is the single home for that idiom.
+The CSR-k, SELL-C-σ and segsum kernels reduce a tile of nnz slots the same
+way, and this module is the single home for it:
+
+1. the tile's value stream is loaded as f32 (:func:`dequant`; int8 grouped
+   scales are applied one 128-lane group at a time);
+2. ``x[col]`` is gathered as one-hot matmuls on the MXU (:func:`gather`):
+   SpMV is bandwidth-bound, so spending idle MXU FLOPs to avoid scattered
+   memory access is the right trade on TPU;
+3. the per-slot products are reduced into output rows by a second one-hot
+   matmul, in fixed 128-slot groups (:func:`reduce_rows`).
+
+Layout (Mosaic's): a tile is a ``[1, S]`` lane vector of slots, and column
+and row indices stay on lanes.  x arrives transposed as ``[P·B, L]`` — the B
+right-hand sides on sublanes, columns on lanes — so every one-hot is
+``[chunk, S]`` with the chunk's columns on sublanes and the tile's slots on
+lanes, and every output is a lane-dense ``[B, rows]`` block.
+
+Precision: every MXU operand is exact in bf16, so no result depends on how
+the compiler treats an f32 matmul (on v5e Mosaic rounds f32 operands of a
+default-precision dot to bf16).  f32 data is cut into three terms whose sum
+is exactly the value (:func:`split3`): an f32 x outside the kernel
+(:func:`split_f32`, P = 3), and the slot products inside the reduce.  A
+one-hot is exact in bf16, so one bf16 pass with f32 accumulation returns
+each x term exactly and their f32 sum rebuilds x bit for bit; the reduce
+multiplies exact terms and accumulates in f32, an f32 summation.  Each is
+one MXU pass, where an f32 ``Precision.HIGHEST`` dot would take six.  A bf16
+x is gathered as is (P = 1).
+
+The execution mode itself follows the platform (:func:`resolve_interpret`).
+The matmul operands are bf16 when compiled for the MXU and f32 in the
+interpreter (:func:`gather_dtype`): XLA:CPU has no bf16 × bf16 → f32 dot for
+every shape, and the terms are exact in both, so both give the same values.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+LANE = 128
+
+#: Precision of both one-hot matmuls: one MXU pass, exact because every
+#: operand is exact in bf16 (see the module doc).
+ONE_PASS = jax.lax.Precision.DEFAULT
+
+#: x columns each whole-x kernel may address (``prepare`` refuses wider
+#: matrices).  These kernels hold all of x in VMEM — 32–48 bytes per column
+#: at up to eight right-hand sides — while the CSR-k kernel is bounded by its
+#: banded window and has no such limit.  SELL-C-σ and segsum also sweep all
+#: of x in every tile's one-hot gather, so their work grows as n·nnz and
+#: their limit is set by time per SpMV, not by VMEM: at 65,536 columns one
+#: SpMV already takes 0.26 s (segsum, 4.9M nnz) and 0.30 s (SELL-C-σ, 0.28M
+#: nnz) on one TPU v5e.  The DIA plane reads a window per row block and is
+#: limited by VMEM alone.
+WHOLE_X_MAX_COLS = {"sellcs": 1 << 16, "segsum": 1 << 16, "diahybrid": 1 << 20}
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas execution mode: an explicit choice, else the platform's.
+
+    ``None`` follows ``jax.default_backend()``: the CPU runs the kernels in
+    the Pallas interpreter, a TPU compiles them with Mosaic.  Any other
+    platform has no Pallas path here and raises — nothing silently drops to
+    the interpreter on an accelerator.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise ValueError(
+        f"no Pallas execution mode for platform {backend!r} "
+        "(cpu → interpret, tpu → compiled)"
+    )
+
+
+def round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
 
 
 def pick_chunk(S: int, chunk: int) -> int:
     """Largest 128-multiple ≤ ``chunk`` that divides ``S``; falls back to S.
 
-    ``S`` (the slot count) is a multiple of 128 by construction in both tile
-    views, so the 128 fallback always divides it; the final ``S`` fallback
-    only triggers for non-aligned S (possible in hand-built tests).
+    ``S`` (a window or x width) is a multiple of 128 by construction, so the
+    128 fallback always divides it; the final ``S`` fallback only triggers
+    for non-aligned S (possible in hand-built tests).
     """
-    chunk = max(min(chunk, S) // 128 * 128, 128)
-    while chunk > 128 and S % chunk:
-        chunk -= 128
+    chunk = max(min(chunk, S) // LANE * LANE, LANE)
+    while chunk > LANE and S % chunk:
+        chunk -= LANE
     return chunk if S % chunk == 0 else S
 
 
-def gather_onehot(src: jax.Array, idx: jax.Array, chunk: int) -> jax.Array:
-    """Gather src[idx] as chunked one-hot matmuls (MXU-friendly).
+def pad_tiles(arrays, tiles_per_step: int):
+    """Zero-pad ``[T, ...]`` tile streams to at least one full grid step.
 
-    src: [N] vector or [N, B] multi-vector block; idx: [S] int32 with S a
-    multiple of 128.  Returns [S] (resp. [S, B]) float32.  Out-of-range idx
-    rows produce 0 (no matching one-hot column).
-
-    The batched form builds each chunk's one-hot exactly once and multiplies
-    it against the whole [N, B] block — the one-hot construction (the
-    bandwidth-side cost of this idiom) is amortised over all B columns, which
-    is what makes multi-vector SpMM nearly free relative to B SpMV calls.
+    Mosaic cannot lay out a ``[tiles_per_step, S]`` block over an array with
+    fewer rows.  Padding tiles have value 0 and are inert; only small slot
+    buckets (T < tiles_per_step) take this copy.
     """
-    if src.ndim == 2:
-        return _gather_onehot_batched(src, idx, chunk)
-    (S,) = idx.shape
-    (N,) = src.shape
-    chunk = pick_chunk(S, chunk)
-    num_chunks = S // chunk
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, N), 1)
+    T = arrays[0].shape[0]
+    if T >= tiles_per_step:
+        return arrays
+    return [None if a is None else
+            jnp.pad(a, [(0, tiles_per_step - T)] + [(0, 0)] * (a.ndim - 1))
+            for a in arrays]
+
+
+def gather_dtype(interpret: bool):
+    """Operand dtype of the one-hot gather matmul (see the module doc)."""
+    return jnp.float32 if interpret else jnp.bfloat16
+
+
+def split3(v: jax.Array):
+    """Three f32 terms, each exact in bf16, with ``(hi + mid) + lo == v``.
+
+    ``hi`` and ``mid`` are cut by masking the low 16 bits, not by an
+    f32 → bf16 → f32 round trip: XLA:TPU may drop such a round trip as
+    excess precision, which would leave ``hi == v`` and zero the other
+    terms.  The 24 significand bits split 8 + 8 + ≤ 8, so every term is
+    exact in bf16 however it is later cast.
+    """
+    def top8(u):
+        bits = jax.lax.bitcast_convert_type(u, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    hi = top8(v)
+    r = v - hi
+    mid = top8(r)
+    return hi, mid, r - mid
+
+
+def split_f32(xT: jax.Array) -> tuple[jax.Array, int]:
+    """x as the kernels' gather operand: ``[B, L]`` → (``[P·B, L]``, P).
+
+    f32 x becomes its three :func:`split3` terms stacked as bf16 (P = 3);
+    any other dtype passes through with P = 1.
+    """
+    if xT.dtype != jnp.float32:
+        return xT, 1
+    parts = [p.astype(jnp.bfloat16) for p in split3(xT)]
+    return jnp.concatenate(parts, axis=0), 3
+
+
+def dequant(vals: jax.Array, scale) -> jax.Array:
+    """A ``[N, S]`` value block as f32, int8 grouped scales applied.
+
+    ``scale`` (``[N, S/128]`` f32 or None) holds one symmetric scale per 128
+    slots (``repro.sparse.csrk.INT8_GROUP``); bf16/f32 streams pass None and
+    only upcast.  Accumulation downstream is always f32.
+    """
+    v = vals.astype(jnp.float32)
+    if scale is None:
+        return v
+    return jnp.concatenate(
+        [v[:, g * LANE:(g + 1) * LANE] * scale[:, g:g + 1]
+         for g in range(scale.shape[1])],
+        axis=1,
+    )
+
+
+def gather(x_ref, lc: jax.Array, *, base: int, chunk: int, parts: int,
+           dot_dtype):
+    """``x[:, lc − base]`` for one tile, as chunked one-hot matmuls.
+
+    Args:
+      x_ref: ``[P·B, W]`` ref of the x columns ``[base, base + W)``, in the
+        :func:`split_f32` layout.
+      lc: ``[1, S]`` int32 column indices on lanes.  Slots outside
+        ``[base, base + W)`` gather 0.
+      chunk: x columns per one-hot (a 128-multiple dividing W).
+      parts: P.
+      dot_dtype: operand dtype of the matmul (:func:`gather_dtype`).
+
+    Returns:
+      ``[B, S]`` f32, exact: every slot sums one term and zeros.
+    """
+    PB, W = x_ref.shape
+    S = lc.shape[1]
 
     def body(i, acc):
-        idx_c = jax.lax.dynamic_slice(idx, (i * chunk,), (chunk,))
-        onehot = (idx_c[:, None] == cols).astype(src.dtype)        # [chunk, N]
-        g = jnp.dot(onehot, src, preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice(acc, g.astype(acc.dtype), (i * chunk,))
+        c0 = pl.multiple_of(i * chunk, LANE)
+        xs = x_ref[:, pl.ds(c0, chunk)].astype(dot_dtype)          # [P·B, chunk]
+        cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, S), 0) + (base + c0)
+        onehot = (cols == lc).astype(dot_dtype)                    # [chunk, S]
+        return acc + jnp.dot(xs, onehot, precision=ONE_PASS,
+                             preferred_element_type=jnp.float32)
 
-    acc0 = jnp.zeros((S,), jnp.float32)
-    return jax.lax.fori_loop(0, num_chunks, body, acc0)
+    g = jax.lax.fori_loop(
+        0, W // chunk, body, jnp.zeros((PB, S), jnp.float32)
+    )
+    B = PB // parts
+    out = g[:B]
+    for p in range(1, parts):
+        out = out + g[p * B:(p + 1) * B]
+    return out
 
 
-def _gather_onehot_batched(src: jax.Array, idx: jax.Array, chunk: int) -> jax.Array:
-    """Batched gather: src [N, B], idx [S] → [S, B] float32.
+def gather_take(x_refs, lc: jax.Array, parts: int) -> jax.Array:
+    """``gather_mode="take"``: the same values through ``jnp.take``.
 
-    Identical chunking/one-hot structure to the vector path; the only change
-    is that the per-chunk matmul contracts against a [N, B] block.
+    ``x_refs`` are the contiguous x pieces starting at column 0.  Mosaic has
+    no general lane gather, so this path runs in interpret mode only
+    (``prepare`` refuses it on a TPU).
     """
-    (S,) = idx.shape
-    N, B = src.shape
-    chunk = pick_chunk(S, chunk)
-    num_chunks = S // chunk
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, N), 1)
+    xw = jnp.concatenate([r[...] for r in x_refs], axis=1).astype(jnp.float32)
+    g = jnp.take(xw, lc[0], axis=1)                                # [P·B, S]
+    B = g.shape[0] // parts
+    out = g[:B]
+    for p in range(1, parts):
+        out = out + g[p * B:(p + 1) * B]
+    return out
 
-    def body(i, acc):
-        idx_c = jax.lax.dynamic_slice(idx, (i * chunk,), (chunk,))
-        onehot = (idx_c[:, None] == cols).astype(src.dtype)        # [chunk, N]
-        g = jnp.dot(onehot, src, preferred_element_type=jnp.float32)  # [chunk, B]
-        return jax.lax.dynamic_update_slice(acc, g.astype(acc.dtype), (i * chunk, 0))
 
-    acc0 = jnp.zeros((S, B), jnp.float32)
-    return jax.lax.fori_loop(0, num_chunks, body, acc0)
+def reduce_rows(contrib: jax.Array, lr: jax.Array, rows: int, dot_dtype):
+    """Sum ``[B, S]`` slot products into ``[B, rows]`` by row id ``lr [1, S]``.
+
+    The products are cut into their :func:`split3` terms (stacked ``[3B,
+    S]``), so the one-hot matmul multiplies exactly; one matmul per 128-slot
+    group, accumulated in group order.  The fixed group shape makes a tile's
+    result independent of how many all-padding groups trail it (padding
+    contributes exact zeros), so slot buckets and the monolithic layout
+    agree bit for bit.
+    """
+    B, S = contrib.shape
+    terms = jnp.concatenate(split3(contrib), axis=0).astype(dot_dtype)  # [3B, S]
+    y = jnp.zeros((3 * B, rows), jnp.float32)
+    for g in range(S // LANE):
+        sl = slice(g * LANE, (g + 1) * LANE)
+        onehot = (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, LANE), 0) == lr[:, sl]
+        ).astype(dot_dtype)                                         # [rows, 128]
+        y = y + jax.lax.dot_general(
+            terms[:, sl], onehot, (((1,), (1,)), ((), ())),
+            precision=ONE_PASS, preferred_element_type=jnp.float32,
+        )
+    return (y[:B] + y[B:2 * B]) + y[2 * B:]
+
+
+def tile_rows(v, lc, lr, x_refs, bases, *, rows, chunk, parts, gather_mode,
+              dot_dtype):
+    """One tile end to end: ``Σ_s v[s]·x[:, lc[s]]`` into rows ``lr``.
+
+    ``x_refs``/``bases`` cover the tile's column range in order; returns
+    ``[B, rows]`` f32.
+    """
+    if gather_mode == "take":
+        g = gather_take(x_refs, lc, parts)
+    else:
+        g = gather(x_refs[0], lc, base=bases[0], chunk=chunk, parts=parts,
+                   dot_dtype=dot_dtype)
+        for ref, b in zip(x_refs[1:], bases[1:]):
+            g = g + gather(ref, lc, base=b, chunk=chunk, parts=parts,
+                           dot_dtype=dot_dtype)
+    return reduce_rows(v * g, lr, rows, dot_dtype)
+
+
+def vmem_limit(nbytes: int) -> int:
+    """Scoped-VMEM request for a kernel whose buffers total ``nbytes``.
+
+    Twice the estimate plus headroom for Mosaic's temporaries, clamped to
+    what one v5e core can grant (128 MiB physical).
+    """
+    return int(min(max(2 * nbytes + (8 << 20), 16 << 20), 100 << 20))

@@ -20,6 +20,7 @@ from repro.sparse import (
     SELLCSTiles,
 )
 from repro.kernels import ref
+from repro.kernels.gather import LANE, round_up
 from repro.kernels.spmv_csrk import spmv_csrk_tiles_pallas
 from repro.kernels.spmv_diahybrid import spmv_dia_pallas
 from repro.kernels.spmv_ell import spmv_ell_pallas
@@ -92,7 +93,7 @@ def spmv_csrk(
     *,
     gather_mode: str = "onehot",
     gather_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """CSR-k SpMV via the Pallas kernel (+ pure-jnp COO remainder pass).
 
@@ -129,7 +130,7 @@ def spmv_csrk_bucketed(
     *,
     gather_mode: str = "onehot",
     gather_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Slot-bucketed CSR-k SpMV: one Pallas launch per slot bucket.
 
@@ -179,7 +180,7 @@ def spmv_sellcs(
     *,
     gather_mode: str = "onehot",
     gather_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """SELL-C-σ SpMV via the Pallas kernel (+ scatter back to original rows).
 
@@ -212,7 +213,7 @@ def spmv_segsum(
     *,
     gather_mode: str = "onehot",
     gather_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Speculative segmented-sum SpMV: Pallas partials + the carry/patch pass.
 
@@ -247,26 +248,28 @@ def spmv_diahybrid(
     x: jax.Array,
     *,
     row_tile: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Partially-diagonal hybrid SpMV: Pallas DIA plane + CSR-oracle remainder.
 
     x is extended with the kernel's ``lead`` zero margin so every shifted
     diagonal slice is in-range (off-matrix reads pair zero slot values with
     zero margin reads — inert on both sides); the CSR remainder rides the
-    existing ``ref.spmv_csr`` / ``ref.spmm_csr`` path, added after the plane
-    in the same order the oracle uses.  ``x`` may be [n] or [n, B].
+    CSR segment-sum (``ref.spmv_csr`` / ``ref.spmm_csr``), the hybrid's
+    second half, added after the plane in the same order the oracle uses.
+    ``row_tile`` is rounded up to a 128-multiple (the kernel's lane-dense
+    output block).  ``x`` may be [n] or [n, B].
     """
     m, n = mat.shape
     offs = mat.offsets
     if not offs:
         y = jnp.zeros((m,) + x.shape[1:], jnp.float32).astype(x.dtype)
     else:
-        row_tile = min(row_tile, max(8, m))
-        m_pad = -(-m // row_tile) * row_tile
+        row_tile = min(round_up(row_tile, LANE), round_up(m, LANE))
+        m_pad = round_up(m, row_tile)
         lead = max(0, -min(offs))
-        hi = max(max(offs), 0)
-        L = lead + max(m_pad + hi, n)
+        span = round_up(max(max(offs) + lead, 1), LANE)
+        L = round_up(max(m_pad + span, lead + n), LANE)
         pad = [(lead, L - lead - n)] + [(0, 0)] * (x.ndim - 1)
         x_ext = jnp.pad(x, pad).astype(jnp.float32)
         plane = jnp.pad(mat.diag_vals, ((0, 0), (0, m_pad - m)))
@@ -288,7 +291,8 @@ def spmv_diahybrid(
 
 
 @annotated("repro.spmv_ell", count_section="kernels")
-def spmv_ell(mat: ELLMatrix, x: jax.Array, *, row_tile: int = 256, interpret: bool = True):
+def spmv_ell(mat: ELLMatrix, x: jax.Array, *, row_tile: int = 256,
+             interpret: bool | None = None):
     """ELL SpMV via the Pallas baseline kernel (rows padded to the tile)."""
     m = mat.vals.shape[0]
     row_tile = min(row_tile, max(8, m))

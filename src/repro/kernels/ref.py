@@ -28,9 +28,9 @@ from repro.obs import annotated
 def _tile_vals_f32(vals: jax.Array, val_scale) -> jax.Array:
     """Tile values as f32: upcast bf16/f32, dequantize int8 grouped scales.
 
-    Mirrors the in-kernel dequantization (spmv_csrk._dequant_slots /
-    spmv_sellcs._dequant_chunk): scale groups run along the last (slot/lane)
-    axis, one f32 scale per ``vals.shape[-1] // val_scale.shape[-1]`` slots.
+    Mirrors the in-kernel dequantization (``repro.kernels.gather.dequant``):
+    scale groups run along the last (slot/lane) axis, one f32 scale per
+    ``vals.shape[-1] // val_scale.shape[-1]`` slots.
     """
     v = vals.astype(jnp.float32)
     if val_scale is not None:

@@ -1,27 +1,23 @@
 """Pallas TPU kernel for CSR-k SpMV (the paper's GPUSpMV-3/3.5, TPU-adapted).
 
 Mapping (DESIGN §2):
-  * one super-super-row  → one grid step (one HBM→VMEM tile move)
-  * super-rows / rows    → sublane-dimension sub-tiles inside the step
-  * intra-row nnz        → lane dimension (the GPUSpMV-3.5 reduction)
-  * x[col_idx] gather    → contiguous banded x-window (two adjacent blocks of
-                           ``window`` columns, placed by a scalar-prefetch
-                           index map) + in-VMEM gather
+  * super-super-rows     → tiles; :data:`TILES_PER_STEP` tiles per grid step
+                           (one ``[8, S]`` HBM→VMEM move per tile stream, the
+                           sublane-aligned block Mosaic requires)
+  * intra-tile nnz slots → lanes
+  * x[col_idx] gather    → contiguous banded x-window per tile (two adjacent
+                           blocks of ``window`` columns, placed by a
+                           scalar-prefetch index map) + one-hot MXU gather
+  * rows                 → one-hot MXU reduce into a lane-dense ``[B, R]``
+                           output block per tile
 
-The in-VMEM gather and the per-row segmented reduction are both expressed as
-one-hot matmuls so they run on the MXU — the TPU-native substitute for the
-CUDA per-thread gather and the shared-memory ``temp[]`` tree reduction.  SpMV
-is bandwidth-bound (paper Fig. 1), so spending idle MXU FLOPs to avoid
-scattered HBM access is the right trade on this hardware.
+The gather and the segmented row reduction are the shared one-hot idiom of
+:mod:`repro.kernels.gather` — the TPU-native substitute for the CUDA
+per-thread gather and the shared-memory ``temp[]`` tree reduction.
 
-Validated in ``interpret=True`` mode on CPU against ``ref.spmv_csrk_tiles``
-and ``ref.spmv_csr`` (tests/test_kernels.py sweeps shapes and dtypes).
-
-Requires ``jax.experimental.pallas.tpu.PrefetchScalarGridSpec`` (jax ≥ 0.4.x;
-CI pins 0.4.37) — the x-window placement needs scalar prefetch, and a plain
-``GridSpec`` cannot express it (an earlier try/except fallback to GridSpec
-could never have run: the operand list and index-map arity only fit the
-prefetch spec).
+Checked in interpret mode against ``ref.spmv_csrk_tiles`` and
+``ref.spmv_csr`` (tests/test_kernels.py) and compiled for v5e at the paper's
+published sizes (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -31,97 +27,49 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.sparse import CSRkTiles
-from repro.kernels.gather import gather_onehot as _gather_onehot
+from repro.kernels.gather import (
+    dequant, gather_dtype, pad_tiles, pick_chunk, resolve_interpret, round_up,
+    split_f32, tile_rows, vmem_limit,
+)
 
 GatherMode = Literal["onehot", "take"]
 
-
-def _reduce_onehot(contrib: jax.Array, lr: jax.Array, rows: int) -> jax.Array:
-    """Segmented row reduction as a one-hot matmul: [S] → [rows].
-
-    ``contrib`` may carry a trailing batch dimension ([S, B] → [rows, B]);
-    the one-hot matrix is built once and shared across the batch.
-    """
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, contrib.shape[0]), 0)
-    onehot = (ridx == lr[None, :]).astype(contrib.dtype)            # [rows, S]
-    return jnp.dot(onehot, contrib, preferred_element_type=jnp.float32)
-
-
-def _dequant_slots(v: jax.Array, scale_ref) -> jax.Array:
-    """Load the [S] value stream as f32, applying int8 grouped scales if given.
-
-    ``scale_ref`` (``[1, S/group]`` f32 or None) carries one symmetric scale
-    per slot group (see ``repro.sparse.csrk.INT8_GROUP``); bf16/f32 streams
-    arrive with ``scale_ref is None`` and only need the f32 upcast.
-    Accumulation downstream is always f32 — compression changes the bytes
-    moved, never the accumulate dtype.
-    """
-    v = v.astype(jnp.float32)
-    if scale_ref is not None:
-        s = scale_ref[0]                                            # [S/G]
-        group = v.shape[0] // s.shape[0]
-        v = v * jnp.repeat(s, group, total_repeat_length=v.shape[0])
-    return v
+#: Tiles per grid step: the sublane count, so each tile-stream block is one
+#: aligned ``[8, S]`` slab for every value dtype.
+TILES_PER_STEP = 8
 
 
 def _kernel(
-    win_ref,       # scalar-prefetch: [T] int32 window block indices (unused in body)
-    vals_ref,      # [1, S]
-    lc_ref,        # [1, S]
-    lr_ref,        # [1, S]
-    *rest,         # ([scale_ref,] x1_ref [window], x2_ref [window], y_ref [R])
-    rows_per_tile: int,
-    gather_chunk: int,
+    win_ref,       # scalar prefetch: [T_pad] int32 window block per tile
+    vals_ref,      # [TB, S]
+    lc_ref,        # [TB, S]
+    lr_ref,        # [TB, S]
+    *rest,         # ([scale_ref [TB, G],] 2·TB x refs [P·B, W], y_ref [TB·B, Rp])
+    tiles: int,
+    batch: int,
+    rows: int,
+    window: int,
+    chunk: int,
+    parts: int,
     gather_mode: GatherMode,
+    has_scale: bool,
+    dot_dtype,
 ):
-    del win_ref  # consumed by the BlockSpec index maps
-    scale_ref = rest[0] if len(rest) == 4 else None
-    x1_ref, x2_ref, y_ref = rest[-3:]
-    xw = jnp.concatenate([x1_ref[...], x2_ref[...]])                # [2W]
-    lc = lc_ref[0]
-    lr = lr_ref[0]
-    v = _dequant_slots(vals_ref[0], scale_ref)
-    if gather_mode == "take":
-        gathered = jnp.take(xw, lc, axis=0).astype(jnp.float32)
-    else:
-        gathered = _gather_onehot(xw, lc, gather_chunk)
-    contrib = v * gathered                                          # [S]
-    y = _reduce_onehot(contrib, lr, rows_per_tile)                  # [R]
-    y_ref[...] = y.astype(y_ref.dtype)
-
-
-def _kernel_batched(
-    win_ref,       # scalar-prefetch: [T] int32 window block indices (unused in body)
-    vals_ref,      # [1, S]
-    lc_ref,        # [1, S]
-    lr_ref,        # [1, S]
-    *rest,         # ([scale_ref,] x1_ref [window,B], x2_ref [window,B], y_ref [R,B])
-    rows_per_tile: int,
-    gather_chunk: int,
-    gather_mode: GatherMode,
-):
-    """SpMM variant: same tile walk, x carries a trailing batch dimension.
-
-    The one-hot gather/reduce matrices are built once per chunk/tile and
-    contracted against the whole [·, B] block — the matrix stream (the
-    bandwidth-bound side) is read exactly once regardless of B.
-    """
-    del win_ref  # consumed by the BlockSpec index maps
-    scale_ref = rest[0] if len(rest) == 4 else None
-    x1_ref, x2_ref, y_ref = rest[-3:]
-    xw = jnp.concatenate([x1_ref[...], x2_ref[...]], axis=0)        # [2W, B]
-    lc = lc_ref[0]
-    lr = lr_ref[0]
-    v = _dequant_slots(vals_ref[0], scale_ref)
-    if gather_mode == "take":
-        gathered = jnp.take(xw, lc, axis=0).astype(jnp.float32)     # [S, B]
-    else:
-        gathered = _gather_onehot(xw, lc, gather_chunk)             # [S, B]
-    contrib = v[:, None] * gathered                                 # [S, B]
-    y = _reduce_onehot(contrib, lr, rows_per_tile)                  # [R, B]
-    y_ref[...] = y.astype(y_ref.dtype)
+    del win_ref  # consumed by the x BlockSpec index maps
+    scale_ref = rest[0] if has_scale else None
+    x_refs, y_ref = rest[int(has_scale):-1], rest[-1]
+    v = dequant(vals_ref[...], None if scale_ref is None else scale_ref[...])
+    lc, lr = lc_ref[...], lr_ref[...]
+    for j in range(tiles):
+        y = tile_rows(
+            v[j:j + 1], lc[j:j + 1], lr[j:j + 1],
+            x_refs[2 * j:2 * j + 2], (0, window),
+            rows=rows, chunk=chunk, parts=parts, gather_mode=gather_mode,
+            dot_dtype=dot_dtype,
+        )
+        y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
 
 
 @functools.partial(
@@ -140,7 +88,7 @@ def spmv_csrk_tiles_pallas(
     window: int,
     gather_chunk: int = 512,
     gather_mode: GatherMode = "onehot",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Run the CSR-k Pallas kernel over all tiles.
 
@@ -153,115 +101,67 @@ def spmv_csrk_tiles_pallas(
       val_scale: optional [T, S/group] f32 per-group scales for int8 values
         (dequantized in-kernel; accumulation stays f32).
       rows_per_tile / window: static tile geometry from :class:`CSRkTiles`.
+      gather_chunk: x-window columns per one-hot slab.
 
     Returns:
-      y of [T · R] (resp. [T · R, B]).  The vector path is unchanged from
-      the single-RHS kernel (bit-for-bit).
+      y of [T · R] (resp. [T · R, B]).  A vector is the B = 1 case of the
+      block path, so ``op(x)`` and ``op(x[:, None])[:, 0]`` agree bit for
+      bit.
 
-    The kernel is pure in the tile arrays, so the distributed layer can run
-    it unmodified inside ``shard_map`` on a contiguous slice of tiles — each
-    shard is just a smaller T with identical statics, which is what makes
-    the sharded operator bit-for-bit equal to the global launch.
+    The kernel is pure in the tile arrays and each tile's result depends only
+    on its own slots, so the distributed layer can run it unmodified inside
+    ``shard_map`` on any subset of tiles — the property that makes the
+    sharded operator bit-for-bit equal to the global launch.
     """
-    if x_padded.ndim == 2:
-        return _spmm_csrk_tiles_pallas_batched(
-            vals, local_col, local_row, win_block, x_padded, val_scale,
-            rows_per_tile=rows_per_tile, window=window,
-            gather_chunk=gather_chunk, gather_mode=gather_mode,
-            interpret=interpret,
-        )
+    interpret = resolve_interpret(interpret)
+    vector = x_padded.ndim == 1
+    xT = x_padded[None, :] if vector else x_padded.T               # [B, L]
+    B = xT.shape[0]
+    xg, parts = split_f32(xT)                                      # [P·B, L]
     T, S = vals.shape
+    TB = TILES_PER_STEP
+    steps = -(-T // TB)
+    vals, local_col, local_row, val_scale = pad_tiles(
+        [vals, local_col, local_row, val_scale], TB
+    )
+    Rp = round_up(rows_per_tile, 8)
+    chunk = pick_chunk(window, gather_chunk)
 
-    # Scalar-prefetch grid spec: win_block rides ahead of the grid so the
-    # x-window index maps can read it.
-    from jax.experimental.pallas import tpu as pltpu
-
-    in_specs = [
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-    ]
+    tile_spec = pl.BlockSpec((TB, S), lambda t, w: (t, 0))
+    in_specs = [tile_spec] * 3
     operands = [vals, local_col, local_row]
     if val_scale is not None:
-        G = val_scale.shape[1]
-        in_specs.append(pl.BlockSpec((1, G), lambda t, w: (t, 0)))
+        in_specs.append(pl.BlockSpec((TB, val_scale.shape[1]), lambda t, w: (t, 0)))
         operands.append(val_scale)
-    in_specs += [
-        pl.BlockSpec((window,), lambda t, w: (w[t],)),
-        pl.BlockSpec((window,), lambda t, w: (w[t] + 1,)),
-    ]
+    for j in range(TB):
+        # tile t·TB+j reads window blocks w and w+1 of x
+        in_specs += [
+            pl.BlockSpec((parts * B, window), lambda t, w, j=j: (0, w[t * TB + j])),
+            pl.BlockSpec((parts * B, window), lambda t, w, j=j: (0, w[t * TB + j] + 1)),
+        ]
+        operands += [xg, xg]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(T,),
+        grid=(steps,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_per_tile,), lambda t, w: (t,)),
+        out_specs=pl.BlockSpec((TB * B, Rp), lambda t, w: (t, 0)),
     )
-
     kernel = functools.partial(
-        _kernel,
-        rows_per_tile=rows_per_tile,
-        gather_chunk=gather_chunk,
-        gather_mode=gather_mode,
+        _kernel, tiles=TB, batch=B, rows=Rp, window=window, chunk=chunk,
+        parts=parts, gather_mode=gather_mode, has_scale=val_scale is not None,
+        dot_dtype=gather_dtype(interpret),
     )
-    return pl.pallas_call(
+    # double-buffered tile and x blocks, plus one one-hot slab and its product
+    vmem = (2 * (4 * TB * S * 4 + 2 * TB * max(parts * B, 16) * window * 4)
+            + 3 * chunk * S * 4)
+    y = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * rows_per_tile,), x_padded.dtype),
+        out_shape=jax.ShapeDtypeStruct((steps * TB * B, Rp), x_padded.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(vmem)),
         interpret=interpret,
-    )(win_block, *operands, x_padded, x_padded)
-
-
-def _spmm_csrk_tiles_pallas_batched(
-    vals: jax.Array,       # [T, S]
-    local_col: jax.Array,  # [T, S]
-    local_row: jax.Array,  # [T, S]
-    win_block: jax.Array,  # [T]
-    x_padded: jax.Array,   # [(nblocks+1) * window, B]
-    val_scale: jax.Array | None = None,
-    *,
-    rows_per_tile: int,
-    window: int,
-    gather_chunk: int,
-    gather_mode: GatherMode,
-    interpret: bool,
-) -> jax.Array:
-    """Batched (SpMM) launch: identical grid/tile walk, x blocks gain a
-    trailing batch dimension.  Returns y of [T * R, B]."""
-    T, S = vals.shape
-    B = x_padded.shape[1]
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    in_specs = [
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, w: (t, 0)),
-    ]
-    operands = [vals, local_col, local_row]
-    if val_scale is not None:
-        G = val_scale.shape[1]
-        in_specs.append(pl.BlockSpec((1, G), lambda t, w: (t, 0)))
-        operands.append(val_scale)
-    in_specs += [
-        pl.BlockSpec((window, B), lambda t, w: (w[t], 0)),
-        pl.BlockSpec((window, B), lambda t, w: (w[t] + 1, 0)),
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_per_tile, B), lambda t, w: (t, 0)),
-    )
-
-    kernel = functools.partial(
-        _kernel_batched,
-        rows_per_tile=rows_per_tile,
-        gather_chunk=gather_chunk,
-        gather_mode=gather_mode,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * rows_per_tile, B), x_padded.dtype),
-        interpret=interpret,
-    )(win_block, *operands, x_padded, x_padded)
+        name="spmv_csrk",
+    )(jnp.pad(win_block, (0, steps * TB - T)), *operands)
+    y = y[:T * B].reshape(T, B, Rp)[:, :, :rows_per_tile]
+    y = y.transpose(0, 2, 1).reshape(T * rows_per_tile, B)
+    return y[:, 0] if vector else y

@@ -1,29 +1,32 @@
 """Pallas TPU kernel for the DIA plane of the partially-diagonal hybrid.
 
 Mapping:
-  * one row block      → one grid step ([n_diag, row_tile] value block)
-  * x[col] per diagonal → a statically-unrolled shifted contiguous slice of
-    x (col = row + offset, so a diagonal's x reads are unit-stride — no
-    gather at all, the whole point of extracting dense diagonals)
-  * accumulation       → per-slot f32 products, reduced over the diagonal
-    axis with ``jnp.sum`` — the one formulation that is bit-reproducible
-    between the jitted kernel and the eager oracle: an explicit FMA chain
-    gets single-rounding-fused under jit, and a ones-vector ``dot`` is
-    rewritten by XLA's dot-strength-reduction, but a plain axis reduction
-    lowers to the same pairwise tree in both contexts
+  * one row block       → one grid step ([n_diag, row_tile] value block,
+    ``row_tile`` a 128-multiple so the ``[B, row_tile]`` output is lane-dense)
+  * x[col] per diagonal → one aligned load of the x window the block's
+    diagonals can reach, then a static lane shift per diagonal (col = row +
+    offset, so a diagonal's x reads are unit-stride — no gather at all, the
+    whole point of extracting dense diagonals)
+  * accumulation        → per-slot f32 products, reduced over the diagonal
+    axis with ``jnp.sum`` — the formulation the oracle (``ref._dia_plane``)
+    uses too, so both reduce each row's products in diagonal order
 
-x arrives extended with a ``lead = max(0, −min_offset)`` zero margin on the
-left and a zero margin on the right, so every shifted slice is in-range and
-off-matrix reads are inert zeros (matching the container's zeroed plane).
-The CSR remainder is NOT handled here — ops.py adds it through the existing
-``ref.spmv_csr`` oracle path after the launch, per the hybrid's design.
+x arrives transposed (``[B, L]``) and extended with a ``lead = max(0,
+−min_offset)`` zero margin on the left and zeros on the right, so every
+shifted slice is in range and off-matrix reads are inert zeros (matching the
+container's zeroed plane).  The CSR remainder is NOT handled here — ops.py
+adds it through the CSR segment-sum path after the launch, per the hybrid's
+design.
 
-Unlike SELL-C-σ / segsum, each grid step only reads a ``row_tile``-sized
-x window per diagonal, so VMEM pressure is O(n_diag · row_tile), not O(n) —
-diagonal structure restores the locality that Band-k windows give CSR-k.
+x is held whole in VMEM (:data:`~repro.kernels.gather.WHOLE_X_MAX_COLS`,
+enforced by ``prepare``), but each grid step reads only an
+``row_tile + span`` window of it, so the work per step is O(n_diag ·
+row_tile), not O(n) — diagonal structure restores the locality that Band-k
+windows give CSR-k.
 
-Validated in ``interpret=True`` mode against ``ref.spmv_diahybrid``
-(tests/test_irregular_formats.py).
+Checked in interpret mode against ``ref.spmv_diahybrid``
+(tests/test_irregular_formats.py) and compiled for v5e at the whole-x limit
+(tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -33,49 +36,29 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gather import LANE, resolve_interpret, round_up, vmem_limit
 
 
 def _kernel(
     diag_ref,  # [n_diag, RT]
-    x_ref,     # [L] extended x
-    y_ref,     # [RT]
+    x_ref,     # [B, L] extended x
+    y_ref,     # [B, RT]
     *,
-    offsets: Tuple[int, ...],
-    lead: int,
+    shifts: Tuple[int, ...],
     row_tile: int,
+    span: int,
 ):
-    i0 = pl.program_id(0) * row_tile
-    x = x_ref[...]
-    xs = jnp.stack([                          # static unroll: one slice/diag
-        jax.lax.dynamic_slice(x, (i0 + off + lead,), (row_tile,))
-        for off in offsets
-    ])                                                       # [n_diag, RT]
-    contrib = diag_ref[...].astype(jnp.float32) * xs.astype(jnp.float32)
-    y_ref[...] = jnp.sum(contrib, axis=0).astype(y_ref.dtype)   # [RT]
-
-
-def _kernel_batched(
-    diag_ref,  # [n_diag, RT]
-    x_ref,     # [L, B] extended x block
-    y_ref,     # [RT, B]
-    *,
-    offsets: Tuple[int, ...],
-    lead: int,
-    row_tile: int,
-):
-    """SpMM variant: the diagonal value block (the bandwidth-bound side) is
-    read once for all B right-hand sides."""
-    i0 = pl.program_id(0) * row_tile
-    x = x_ref[...]
-    B = x.shape[1]
-    xs = jnp.stack([
-        jax.lax.dynamic_slice(x, (i0 + off + lead, 0), (row_tile, B))
-        for off in offsets
-    ])                                                       # [n_diag, RT, B]
-    contrib = diag_ref[...].astype(jnp.float32)[..., None] * xs.astype(
-        jnp.float32
-    )
-    y_ref[...] = jnp.sum(contrib, axis=0).astype(y_ref.dtype)   # [RT, B]
+    i0 = pl.multiple_of(pl.program_id(0) * row_tile, LANE)
+    xw = x_ref[:, pl.ds(i0, row_tile + span)]                # [B, RT + span]
+    width = row_tile + span
+    d = diag_ref[...].astype(jnp.float32)                    # [n_diag, RT]
+    contrib = jnp.stack([                                    # static unroll
+        d[k:k + 1] * pltpu.roll(xw, (width - s) % width, 1)[:, :row_tile]
+        for k, s in enumerate(shifts)
+    ])                                                       # [n_diag, B, RT]
+    y_ref[...] = jnp.sum(contrib, axis=0).astype(y_ref.dtype)
 
 
 @functools.partial(
@@ -88,51 +71,45 @@ def spmv_dia_pallas(
     offsets: Tuple[int, ...],
     lead: int,
     row_tile: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Run the DIA-plane kernel over all row blocks.
 
     Args:
       diag_vals: [n_diag, m_pad] plane, rows padded to a ``row_tile``
         multiple (padding rows are zero → inert).
-      x_ext: extended x from ops.py: ``lead`` zeros, then x, zero-padded on
-        the right so every ``i0 + off + lead`` slice is in-range.
-      offsets / lead / row_tile: static geometry (offsets ascending).
+      x_ext: f32 extended x from ops.py: ``lead`` zeros, then x, zeros on
+        the right so that ``L ≥ m_pad + round_up(max_offset + lead, 128)``.
+      offsets / lead / row_tile: static geometry (offsets ascending,
+        ``row_tile`` a 128-multiple).
 
     Returns:
-      The DIA-plane partial y of [m_pad] (resp. [m_pad, B]); the caller
-      truncates to m and adds the CSR remainder.
+      The DIA-plane partial y of [m_pad] (resp. [m_pad, B]) in f32; the
+      caller truncates to m and adds the CSR remainder.
     """
+    interpret = resolve_interpret(interpret)
+    vector = x_ext.ndim == 1
+    xT = x_ext[None, :] if vector else x_ext.T                     # [B, L]
+    B, L = xT.shape
     n_diag, m_pad = diag_vals.shape
-    T = m_pad // row_tile
-    L = x_ext.shape[0]
-    if x_ext.ndim == 2:
-        B = x_ext.shape[1]
-        kernel = functools.partial(
-            _kernel_batched, offsets=offsets, lead=lead, row_tile=row_tile
-        )
-        return pl.pallas_call(
-            kernel,
-            grid=(T,),
-            in_specs=[
-                pl.BlockSpec((n_diag, row_tile), lambda t: (0, t)),
-                pl.BlockSpec((L, B), lambda t: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((row_tile, B), lambda t: (t, 0)),
-            out_shape=jax.ShapeDtypeStruct((m_pad, B), x_ext.dtype),
-            interpret=interpret,
-        )(diag_vals, x_ext)
+    shifts = tuple(off + lead for off in offsets)
+    span = round_up(max(max(shifts), 1), LANE)
     kernel = functools.partial(
-        _kernel, offsets=offsets, lead=lead, row_tile=row_tile
+        _kernel, shifts=shifts, row_tile=row_tile, span=span
     )
-    return pl.pallas_call(
+    vmem = (2 * n_diag * row_tile * 4 + 2 * max(B, 8) * L * 4
+            + (n_diag + 2) * max(B, 8) * (row_tile + span) * 4)
+    y = pl.pallas_call(
         kernel,
-        grid=(T,),
+        grid=(m_pad // row_tile,),
         in_specs=[
             pl.BlockSpec((n_diag, row_tile), lambda t: (0, t)),
-            pl.BlockSpec((L,), lambda t: (0,)),
+            pl.BlockSpec((B, L), lambda t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((row_tile,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((m_pad,), x_ext.dtype),
+        out_specs=pl.BlockSpec((B, row_tile), lambda t: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((B, m_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(vmem)),
         interpret=interpret,
-    )(diag_vals, x_ext)
+        name="spmv_dia",
+    )(diag_vals, xT)
+    return y[0] if vector else y.T

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.gather import resolve_interpret
+
 
 def _kernel(cols_ref, vals_ref, x_ref, y_ref):
     cols = cols_ref[...]                    # [R, K]
@@ -32,8 +34,9 @@ def spmv_ell_pallas(
     x: jax.Array,         # [n]
     *,
     row_tile: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     m, k = vals.shape
     assert m % row_tile == 0, "pad rows to a multiple of row_tile"
     n = x.shape[0]

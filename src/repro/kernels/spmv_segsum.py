@@ -1,10 +1,10 @@
 """Pallas TPU kernel for speculative segmented-sum CSR SpMV.
 
-Mapping, following the SELL-C-σ kernel's idiom (spmv_sellcs.py):
-  * one nnz chunk     → one grid step ([1, S] value/col/segment streams)
-  * x[col_idx] gather → chunked one-hot matmuls on the MXU (gather.py)
-  * per-segment sum   → the CSR-k kernel's one-hot segmented reduce
-    (spmv_csrk._reduce_onehot), [S] slots → [R] speculative partials
+Mapping, following the CSR-k kernel's idiom (spmv_csrk.py):
+  * equal-size nnz chunks → tiles; :data:`TILES_PER_STEP` per grid step
+  * x[col_idx] gather     → one-hot matmuls on the MXU over the whole x
+  * per-segment sum       → the shared one-hot reduce, [S] slots → [R]
+    speculative partials (:mod:`repro.kernels.gather`)
 
 The kernel is *speculative* in Liu & Vinter's sense: each chunk reduces its
 slots by local segment id without knowing whether a segment is a whole row
@@ -14,84 +14,56 @@ row's fragments, however many chunks it spans.  No per-row padding exists
 anywhere, so the launch cost is O(nnz) even for empty-row / power-law
 matrices — the regime where SELL-C-σ's per-chunk width padding explodes.
 
-Like SELL-C-σ there is no banded-window guarantee, so each grid step sees
-the whole (padded) x in VMEM; the registry routes accordingly.
+Like SELL-C-σ there is no banded-window guarantee, so x is held whole in
+VMEM (:data:`~repro.kernels.gather.WHOLE_X_MAX_COLS`, enforced by
+``prepare``); the registry routes accordingly.
 
-Validated in ``interpret=True`` mode against ``ref.spmv_segsum``
-(tests/test_irregular_formats.py sweeps the adversarial families and dtypes).
+Checked in interpret mode against ``ref.spmv_segsum``
+(tests/test_irregular_formats.py sweeps the adversarial families and dtypes)
+and compiled for v5e at the whole-x limit (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import gather_onehot
-from repro.kernels.spmv_csrk import _dequant_slots, _reduce_onehot
+from repro.kernels.gather import (
+    dequant, gather_dtype, pad_tiles, pick_chunk, resolve_interpret, round_up,
+    split_f32, tile_rows, vmem_limit,
+)
+
+#: Chunks per grid step: one aligned ``[8, S]`` slab per slot stream.
+TILES_PER_STEP = 8
 
 
 def _kernel(
-    vals_ref,   # [1, S]
-    col_ref,    # [1, S]
-    lseg_ref,   # [1, S]
-    *rest,      # ([scale_ref,] x_ref [n_pad], y_ref [R])
-    segs_per_chunk: int,
-    gather_chunk: int,
+    vals_ref,   # [TB, S]
+    col_ref,    # [TB, S]
+    lseg_ref,   # [TB, S]
+    *rest,      # ([scale_ref [TB, G],] x_ref [P·B, n_pad], y_ref [TB·B, Rp])
+    tiles: int,
+    batch: int,
+    rows: int,
+    chunk: int,
+    parts: int,
     gather_mode: str,
+    has_scale: bool,
+    dot_dtype,
 ):
-    scale_ref = rest[0] if len(rest) == 3 else None
+    scale_ref = rest[0] if has_scale else None
     x_ref, y_ref = rest[-2:]
-    v = _dequant_slots(vals_ref[0], scale_ref)                     # [S]
-    cols = col_ref[0]
-    x = x_ref[...]                                                 # [n_pad]
-    if gather_mode == "take":
-        gathered = jnp.take(x, cols, axis=0).astype(jnp.float32)
-    else:
-        gathered = gather_onehot(x, cols, gather_chunk)
-    contrib = v * gathered                                         # [S]
-    y = _reduce_onehot(contrib, lseg_ref[0], segs_per_chunk)       # [R]
-    y_ref[...] = y.astype(y_ref.dtype)
-
-
-def _kernel_batched(
-    vals_ref,   # [1, S]
-    col_ref,    # [1, S]
-    lseg_ref,   # [1, S]
-    *rest,      # ([scale_ref,] x_ref [n_pad, B], y_ref [R, B])
-    segs_per_chunk: int,
-    gather_chunk: int,
-    gather_mode: str,
-):
-    """SpMM variant: x carries a trailing batch dimension; the chunk's
-    slot streams (the bandwidth-bound side) are read once for all B.
-
-    The segmented reduce runs once per column as the *vector* one-hot
-    matvec rather than a single [R, S] × [S, B] matmul: XLA's contraction
-    schedule for the 2-D product varies with (R, B) and drifts final-ulp
-    bits away from the oracle's segment-sum, while the matvec form lowers
-    to the same reduction tree — the kernel==oracle bit-exactness contract
-    (tests/test_irregular_formats.py) holds per column, so it must hold
-    for the stack."""
-    scale_ref = rest[0] if len(rest) == 3 else None
-    x_ref, y_ref = rest[-2:]
-    v = _dequant_slots(vals_ref[0], scale_ref)                     # [S]
-    cols = col_ref[0]
-    x = x_ref[...]                                                 # [n_pad, B]
-    if gather_mode == "take":
-        gathered = jnp.take(x, cols, axis=0).astype(jnp.float32)   # [S, B]
-    else:
-        gathered = gather_onehot(x, cols, gather_chunk)            # [S, B]
-    contrib = v[:, None] * gathered                                # [S, B]
-    y = jnp.stack(
-        [
-            _reduce_onehot(contrib[:, b], lseg_ref[0], segs_per_chunk)
-            for b in range(contrib.shape[1])
-        ],
-        axis=1,
-    )                                                              # [R, B]
-    y_ref[...] = y.astype(y_ref.dtype)
+    v = dequant(vals_ref[...], None if scale_ref is None else scale_ref[...])
+    lc, lseg = col_ref[...], lseg_ref[...]
+    for j in range(tiles):
+        y = tile_rows(
+            v[j:j + 1], lc[j:j + 1], lseg[j:j + 1], (x_ref,), (0,),
+            rows=rows, chunk=chunk, parts=parts, gather_mode=gather_mode,
+            dot_dtype=dot_dtype,
+        )
+        y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
 
 
 @functools.partial(
@@ -108,7 +80,7 @@ def spmv_segsum_pallas(
     segs_per_chunk: int,
     gather_chunk: int = 512,
     gather_mode: str = "onehot",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Run the segmented-sum kernel over all chunks.
 
@@ -127,45 +99,46 @@ def spmv_segsum_pallas(
       segment) order.  The caller MUST apply the carry/patch pass — a
       scatter-add through ``seg_row`` (see :func:`repro.kernels.ops.
       spmv_segsum`) — to obtain y; partials of rows spanning chunks are not
-      yet summed here.  The vector path is unchanged from the single-RHS
-      kernel (bit-for-bit).
+      yet summed here.
     """
+    interpret = resolve_interpret(interpret)
+    vector = x_padded.ndim == 1
+    xT = x_padded[None, :] if vector else x_padded.T               # [B, n_pad]
+    B, n_pad = xT.shape
+    xg, parts = split_f32(xT)
     T, S = vals.shape
-    n_pad = x_padded.shape[0]
     R = segs_per_chunk
-    in_specs = [
-        pl.BlockSpec((1, S), lambda t: (t, 0)),
-        pl.BlockSpec((1, S), lambda t: (t, 0)),
-        pl.BlockSpec((1, S), lambda t: (t, 0)),
-    ]
+    Rp = round_up(R, 8)
+    TB = TILES_PER_STEP
+    steps = -(-T // TB)
+    vals, col_idx, local_seg, val_scale = pad_tiles(
+        [vals, col_idx, local_seg, val_scale], TB
+    )
+    chunk = pick_chunk(n_pad, gather_chunk)
+
+    tile_spec = pl.BlockSpec((TB, S), lambda t: (t, 0))
+    in_specs = [tile_spec] * 3
     operands = [vals, col_idx, local_seg]
     if val_scale is not None:
-        G = val_scale.shape[1]
-        in_specs.append(pl.BlockSpec((1, G), lambda t: (t, 0)))
+        in_specs.append(pl.BlockSpec((TB, val_scale.shape[1]), lambda t: (t, 0)))
         operands.append(val_scale)
-    if x_padded.ndim == 2:
-        B = x_padded.shape[1]
-        kernel = functools.partial(
-            _kernel_batched, segs_per_chunk=R,
-            gather_chunk=gather_chunk, gather_mode=gather_mode,
-        )
-        return pl.pallas_call(
-            kernel,
-            grid=(T,),
-            in_specs=in_specs + [pl.BlockSpec((n_pad, B), lambda t: (0, 0))],
-            out_specs=pl.BlockSpec((R, B), lambda t: (t, 0)),
-            out_shape=jax.ShapeDtypeStruct((T * R, B), x_padded.dtype),
-            interpret=interpret,
-        )(*operands, x_padded)
     kernel = functools.partial(
-        _kernel, segs_per_chunk=R,
-        gather_chunk=gather_chunk, gather_mode=gather_mode,
+        _kernel, tiles=TB, batch=B, rows=Rp, chunk=chunk, parts=parts,
+        gather_mode=gather_mode, has_scale=val_scale is not None,
+        dot_dtype=gather_dtype(interpret),
     )
-    return pl.pallas_call(
+    vmem = (2 * 4 * TB * S * 4 + 2 * max(parts * B, 16) * n_pad * 4
+            + 3 * chunk * S * 4)
+    y = pl.pallas_call(
         kernel,
-        grid=(T,),
-        in_specs=in_specs + [pl.BlockSpec((n_pad,), lambda t: (0,))],
-        out_specs=pl.BlockSpec((R,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((T * R,), x_padded.dtype),
+        grid=(steps,),
+        in_specs=in_specs + [pl.BlockSpec((parts * B, n_pad), lambda t: (0, 0))],
+        out_specs=pl.BlockSpec((TB * B, Rp), lambda t: (t, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * TB * B, Rp), x_padded.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(vmem)),
         interpret=interpret,
-    )(*operands, x_padded)
+        name="spmv_segsum",
+    )(*operands, xg)
+    y = y[:T * B].reshape(T, B, Rp)[:, :, :R]
+    y = y.transpose(0, 2, 1).reshape(T * R, B)
+    return y[:, 0] if vector else y

@@ -1,21 +1,21 @@
 """Pallas TPU kernel for SELL-C-σ SpMV (the irregular-matrix path).
 
 Mapping, following the CSR-k kernel's idiom (spmv_csrk.py):
-  * one C-row chunk  → one grid step (C = 8 sublanes, chunk cols = lanes)
-  * x[col_idx] gather → one-hot matmuls on the MXU (SpMV is bandwidth-bound,
-    so idle MXU FLOPs buy us out of scattered HBM access — same trade as the
-    CSR-k kernel)
-  * per-row reduction → a lane-dimension sum (rows are independent inside a
-    chunk, so no segmented reduction is needed — that is SELL's selling point)
+  * C-row chunks      → tiles; :data:`TILES_PER_STEP` chunks per grid step
+  * a chunk's [C, W] slots → one ``[1, C·W]`` lane vector, row r owning lanes
+    ``[r·W, (r+1)·W)``
+  * x[col_idx] gather → one-hot matmuls on the MXU, and the per-row sum →
+    the shared one-hot reduce (:mod:`repro.kernels.gather`)
 
 Unlike CSR-k there is no Band-k window guarantee: irregular matrices scatter
-columns anywhere, so each grid step sees the whole (padded) x in VMEM.  That
-bounds usable n by VMEM — acceptable for the repro suite and exactly the
-scalability pressure the banded CSR-k path avoids; the registry routes
-accordingly.
+columns anywhere, so x is held whole in VMEM and every chunk's gather sweeps
+all of it.  That bounds the usable n (:data:`~repro.kernels.gather.
+WHOLE_X_MAX_COLS`, enforced by ``prepare``) — exactly the scalability
+pressure the banded CSR-k path avoids; the registry routes accordingly.
 
-Validated in ``interpret=True`` mode against ``ref.spmv_sellcs``
-(tests/test_sparse_registry.py sweeps shapes and dtypes).
+Checked in interpret mode against ``ref.spmv_sellcs``
+(tests/test_sparse_registry.py sweeps shapes and dtypes) and compiled for
+v5e at the whole-x limit (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -24,77 +24,46 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import gather_onehot
+from repro.kernels.gather import (
+    dequant, gather_dtype, pick_chunk, resolve_interpret, split_f32, tile_rows,
+    vmem_limit,
+)
 
-
-def _gather_onehot_2d(x: jax.Array, idx: jax.Array, chunk: int) -> jax.Array:
-    """Gather x[idx] for a [C, W] index block via chunked one-hot matmuls.
-
-    x: [n_pad] padded vector or [n_pad, B] block; idx: [C, W] int32.
-    Returns [C, W] (resp. [C, W, B]) float32 — gather_onehot builds each
-    chunk's one-hot once and contracts it against all trailing columns.
-    """
-    return gather_onehot(x, idx.reshape(-1), chunk).reshape(idx.shape + x.shape[1:])
-
-
-def _dequant_chunk(vals: jax.Array, scale_ref) -> jax.Array:
-    """Load a [C, W] value block as f32, applying int8 lane-group scales.
-
-    ``scale_ref`` (``[1, C, W/group]`` f32 or None) holds one symmetric scale
-    per group of lanes (see ``repro.sparse.csrk.INT8_GROUP``); bf16/f32
-    streams pass ``None`` and only upcast.  Accumulation stays f32 always.
-    """
-    v = vals.astype(jnp.float32)
-    if scale_ref is not None:
-        s = scale_ref[0]                                           # [C, W/G]
-        group = v.shape[1] // s.shape[1]
-        v = v * jnp.repeat(s, group, axis=1, total_repeat_length=v.shape[1])
-    return v
+#: Chunks per grid step (C = 8 rows each → 64 output rows per step).
+TILES_PER_STEP = 8
 
 
 def _kernel(
-    vals_ref,   # [1, C, W]
-    col_ref,    # [1, C, W]
-    *rest,      # ([scale_ref,] x_ref [n_pad], y_ref [C])
-    gather_chunk: int,
+    vals_ref,   # [TB, C, W]
+    col_ref,    # [TB, C, W]
+    *rest,      # ([scale_ref [TB, C, W/G],] x_ref [P·B, n_pad], y_ref [TB·B, C])
+    tiles: int,
+    batch: int,
+    chunk: int,
+    parts: int,
     gather_mode: str,
+    has_scale: bool,
+    dot_dtype,
 ):
-    scale_ref = rest[0] if len(rest) == 3 else None
+    scale_ref = rest[0] if has_scale else None
     x_ref, y_ref = rest[-2:]
-    vals = _dequant_chunk(vals_ref[0], scale_ref)                  # [C, W]
-    cols = col_ref[0]                                              # [C, W]
-    x = x_ref[...]                                                 # [n_pad]
-    if gather_mode == "take":
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-        gathered = gathered.astype(jnp.float32)
-    else:
-        gathered = _gather_onehot_2d(x, cols, gather_chunk)
-    contrib = vals * gathered                                      # [C, W]
-    y_ref[...] = jnp.sum(contrib, axis=1).astype(y_ref.dtype)      # [C]
-
-
-def _kernel_batched(
-    vals_ref,   # [1, C, W]
-    col_ref,    # [1, C, W]
-    *rest,      # ([scale_ref,] x_ref [n_pad, B], y_ref [C, B])
-    gather_chunk: int,
-    gather_mode: str,
-):
-    """SpMM variant: x carries a trailing batch dimension; the chunk's
-    vals/cols stream (the bandwidth-bound side) is read once for all B."""
-    scale_ref = rest[0] if len(rest) == 3 else None
-    x_ref, y_ref = rest[-2:]
-    vals = _dequant_chunk(vals_ref[0], scale_ref)                  # [C, W]
-    cols = col_ref[0]                                              # [C, W]
-    x = x_ref[...]                                                 # [n_pad, B]
-    if gather_mode == "take":
-        gathered = jnp.take(x, cols.reshape(-1), axis=0)
-        gathered = gathered.reshape(cols.shape + (x.shape[1],)).astype(jnp.float32)
-    else:
-        gathered = _gather_onehot_2d(x, cols, gather_chunk)        # [C, W, B]
-    contrib = vals[..., None] * gathered                           # [C, W, B]
-    y_ref[...] = jnp.sum(contrib, axis=1).astype(y_ref.dtype)      # [C, B]
+    C, W = vals_ref.shape[1:]
+    lr = jnp.concatenate(
+        [jnp.full((1, W), r, jnp.int32) for r in range(C)], axis=1
+    )                                                              # [1, C·W]
+    for j in range(tiles):
+        v = dequant(vals_ref[j], None if scale_ref is None else scale_ref[j])
+        cols = col_ref[j]
+        v = jnp.concatenate([v[r:r + 1] for r in range(C)], axis=1)
+        lc = jnp.concatenate([cols[r:r + 1] for r in range(C)], axis=1)
+        y = tile_rows(
+            v, lc, lr, (x_ref,), (0,),
+            rows=C, chunk=chunk, parts=parts, gather_mode=gather_mode,
+            dot_dtype=dot_dtype,
+        )
+        y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
 
 
 @functools.partial(
@@ -108,7 +77,7 @@ def spmv_sellcs_pallas(
     *,
     gather_chunk: int = 512,
     gather_mode: str = "onehot",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Run the SELL-C-σ kernel over all chunks.
 
@@ -123,45 +92,49 @@ def spmv_sellcs_pallas(
     Returns:
       y of [T · C] (resp. [T · C, B]) in σ-sorted row order — the caller
       (ops.py, or the sharded operator after reassembly) scatters back to
-      the original ordering via ``row_perm``.  The vector path is unchanged
-      from the single-RHS kernel (bit-for-bit).
+      the original ordering via ``row_perm``.  A vector is the B = 1 case of
+      the block path.
 
     Like the CSR-k kernel, this is pure in the chunk arrays: the distributed
     layer runs it unmodified inside ``shard_map`` over a contiguous slice of
     chunks (smaller T, identical statics).
     """
+    interpret = resolve_interpret(interpret)
+    vector = x_padded.ndim == 1
+    xT = x_padded[None, :] if vector else x_padded.T               # [B, n_pad]
+    B, n_pad = xT.shape
+    xg, parts = split_f32(xT)
     T, C, W = vals.shape
-    n_pad = x_padded.shape[0]
+    TB = TILES_PER_STEP
+    steps = -(-T // TB)
+    chunk = pick_chunk(n_pad, gather_chunk)
+
     in_specs = [
-        pl.BlockSpec((1, C, W), lambda t: (t, 0, 0)),
-        pl.BlockSpec((1, C, W), lambda t: (t, 0, 0)),
+        pl.BlockSpec((TB, C, W), lambda t: (t, 0, 0)),
+        pl.BlockSpec((TB, C, W), lambda t: (t, 0, 0)),
     ]
     operands = [vals, col_idx]
     if val_scale is not None:
-        G = val_scale.shape[2]
-        in_specs.append(pl.BlockSpec((1, C, G), lambda t: (t, 0, 0)))
-        operands.append(val_scale)
-    if x_padded.ndim == 2:
-        B = x_padded.shape[1]
-        kernel = functools.partial(
-            _kernel_batched, gather_chunk=gather_chunk, gather_mode=gather_mode
+        in_specs.append(
+            pl.BlockSpec((TB, C, val_scale.shape[2]), lambda t: (t, 0, 0))
         )
-        return pl.pallas_call(
-            kernel,
-            grid=(T,),
-            in_specs=in_specs + [pl.BlockSpec((n_pad, B), lambda t: (0, 0))],
-            out_specs=pl.BlockSpec((C, B), lambda t: (t, 0)),
-            out_shape=jax.ShapeDtypeStruct((T * C, B), x_padded.dtype),
-            interpret=interpret,
-        )(*operands, x_padded)
+        operands.append(val_scale)
     kernel = functools.partial(
-        _kernel, gather_chunk=gather_chunk, gather_mode=gather_mode
+        _kernel, tiles=TB, batch=B, chunk=chunk, parts=parts,
+        gather_mode=gather_mode, has_scale=val_scale is not None,
+        dot_dtype=gather_dtype(interpret),
     )
-    return pl.pallas_call(
+    vmem = (2 * 3 * TB * C * W * 4 + 2 * max(parts * B, 16) * n_pad * 4
+            + 3 * chunk * C * W * 4)
+    y = pl.pallas_call(
         kernel,
-        grid=(T,),
-        in_specs=in_specs + [pl.BlockSpec((n_pad,), lambda t: (0,))],
-        out_specs=pl.BlockSpec((C,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((T * C,), x_padded.dtype),
+        grid=(steps,),
+        in_specs=in_specs + [pl.BlockSpec((parts * B, n_pad), lambda t: (0, 0))],
+        out_specs=pl.BlockSpec((TB * B, C), lambda t: (t, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * TB * B, C), x_padded.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(vmem)),
         interpret=interpret,
-    )(*operands, x_padded)
+        name="spmv_sellcs",
+    )(*operands, xg)
+    y = y[:T * B].reshape(T, B, C).transpose(0, 2, 1).reshape(T * C, B)
+    return y[:, 0] if vector else y

@@ -196,7 +196,9 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
+    from repro.util.platform import configure_compile_cache
 
+    configure_compile_cache()
     if args.arch is not None:
         run_lm_smoke(args)
     else:

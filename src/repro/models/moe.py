@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.layers import dense_init
 
@@ -230,7 +229,7 @@ def moe_apply_ep(
         aux = jax.lax.pmean(aux, data_axes)                   # agree across shards
         return y.reshape(B, T, D), aux
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -239,6 +238,6 @@ def moe_apply_ep(
             P(data_axes),                          # tokens sharded on batch
         ),
         out_specs=(P(data_axes), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return f(params["router"], params["w_in"], params["w_gate"], params["w_out"], x)

@@ -9,6 +9,9 @@ never touches jax — so scripts can do::
     configure_xla(host_device_count=4, latency_hiding=True)
     import jax   # first init sees the flags
 
+It also owns the one place a compilation-cache path is chosen
+(:func:`configure_compile_cache`).
+
 Two flag groups are managed:
 
 * ``--xla_force_host_platform_device_count=N`` — present the host CPU as N
@@ -32,6 +35,7 @@ our value wins only for the flags we set.
 from __future__ import annotations
 
 import os
+import sys
 from typing import Iterable, Optional
 
 #: Latency-hiding scheduler flags: let the scheduler move independent
@@ -74,6 +78,34 @@ def build_xla_flags(
         parts.extend(LATENCY_HIDING_FLAGS)
     parts.extend(extra)
     return " ".join(parts)
+
+
+#: The checkout root (``src/repro/util/`` → three levels up).
+CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when it is set; otherwise the cache
+    lives in ``<checkout>/.jax_cache`` (git-ignored), a fixed path so a
+    later run finds what an earlier one compiled.  Call before jax
+    compiles anything; if jax is already imported its config is updated
+    too.  Nothing else in the repository sets a cache path.
+
+    Returns:
+      The cache directory in use.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        CHECKOUT, ".jax_cache"
+    )
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def configure_xla(

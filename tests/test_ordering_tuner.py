@@ -210,6 +210,9 @@ def test_load_fitted_device_model_fallbacks(tmp_path):
     bad = tmp_path / "bad.json"
     bad.write_text('{"tpu_v5e": {"ssrs": "oops"}}')
     assert tuner.load_fitted_device_model(str(bad)) is tuner.TPU_V5E
+    # a device with no hand-set model has nothing to fall back to
+    with pytest.raises(ValueError, match="no device model"):
+        tuner.load_fitted_device_model(str(empty), name="tpu_v9")
 
 
 def test_env_var_activates_fitted_model(tmp_path, monkeypatch):

@@ -2,13 +2,15 @@
 plus the B=1 bit-identity regression against the single-vector kernels."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core.solvers import block_cg, block_power_iteration, cg
 from repro.core.spmv import prepare, spmm, spmv
 from repro.configs.spmv_suite import grid_laplacian_2d
 from repro.kernels import ops, ref
-from repro.kernels.gather import gather_onehot
+from repro.kernels.gather import gather, gather_dtype, split_f32
 from repro.sparse import CSRMatrix, build_csrk, sellcs_from_csr, tiles_from_csrk
 
 
@@ -79,13 +81,29 @@ def test_spmm_sellcs_kernel_matches_oracle(rng):
     np.testing.assert_allclose(np.asarray(Y), dense @ X, rtol=2e-3, atol=2e-4)
 
 
+def _gather(xT, idx):
+    """The kernels' one-hot gather of x[:, idx], run in a Pallas call."""
+    xg, parts = split_f32(jnp.asarray(xT))
+
+    def kernel(x_ref, idx_ref, o_ref):
+        o_ref[...] = gather(x_ref, idx_ref[...], base=0, chunk=128, parts=parts,
+                            dot_dtype=gather_dtype(interpret=True))
+
+    return pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=jax.ShapeDtypeStruct((xT.shape[0], idx.size), jnp.float32),
+    )(xg, jnp.asarray(idx)[None, :])
+
+
 def test_gather_onehot_batched_matches_looped(rng):
-    src = rng.standard_normal((96, 6)).astype(np.float32)
-    idx = rng.integers(0, 96, size=256).astype(np.int32)
-    batched = np.asarray(gather_onehot(jnp.asarray(src), jnp.asarray(idx), 128))
-    for b in range(src.shape[1]):
-        col = np.asarray(gather_onehot(jnp.asarray(src[:, b]), jnp.asarray(idx), 128))
-        np.testing.assert_array_equal(batched[:, b], col)
+    src = rng.standard_normal((6, 256)).astype(np.float32)   # [B, columns]
+    idx = rng.integers(0, 256, size=384).astype(np.int32)
+    batched = np.asarray(_gather(src, idx))
+    # exact: three bf16 terms of x, each picked out by a one-hot
+    np.testing.assert_array_equal(batched, src[:, idx])
+    for b in range(src.shape[0]):
+        col = np.asarray(_gather(src[b:b + 1], idx))
+        np.testing.assert_array_equal(batched[b], col[0])
 
 
 def test_spmm_out_of_window_remainder_batched(rng):

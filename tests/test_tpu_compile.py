@@ -1,0 +1,131 @@
+"""Every Pallas SpMV kernel compiles for a TPU v5e at deployment shapes.
+
+Nothing runs: each case lowers a kernel for a described (not attached) v5e
+chip and checks that the compiled program holds the Mosaic kernel
+(``tpu_custom_call``).  This catches misaligned blocks, unsupported ops and
+VMEM overruns that interpret mode cannot see, at no chip time.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU compiler library, so
+under several pytest workers only the worker running this file loads it.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.gather import WHOLE_X_MAX_COLS
+from repro.kernels.spmv_csrk import spmv_csrk_tiles_pallas
+from repro.kernels.spmv_diahybrid import spmv_dia_pallas
+from repro.kernels.spmv_segsum import spmv_segsum_pallas
+from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
+
+#: ecology1 at its published size (1,000,000 rows, 4,996,000 nnz) after
+#: Band-k and the v5e tuner: its two slot buckets as (tiles T, slots S), rows
+#: per tile R and the x-window block width W.  The one-tile bucket is the
+#: case a whole grid step has to be padded for.
+ECOLOGY1 = dict(buckets=((1, 384), (8928, 640)), R=112, W=6784, n=1_000_000)
+
+VALUE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent cache
+    # but never read back without one: keep the cache out of these compiles
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _x(n_pad, B, sharding):
+    return _spec((n_pad,) if B == 1 else (n_pad, B), jnp.float32, sharding)
+
+
+def _assert_mosaic(fn, *args, **statics):
+    text = fn.lower(*args, **statics, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B", [1, 8])
+@pytest.mark.parametrize("vd", ["f32", "bf16", "int8"])
+def test_csrk_compiles_at_ecology1(one_chip, vd, B):
+    R, W = ECOLOGY1["R"], ECOLOGY1["W"]
+    L = (-(-ECOLOGY1["n"] // W) + 1) * W
+    for T, S in ECOLOGY1["buckets"]:
+        scale = (_spec((T, S // 128), jnp.float32, one_chip)
+                 if vd == "int8" else None)
+        _assert_mosaic(
+            spmv_csrk_tiles_pallas,
+            _spec((T, S), VALUE_DTYPES[vd], one_chip),
+            _spec((T, S), jnp.int32, one_chip),
+            _spec((T, S), jnp.int32, one_chip),
+            _spec((T,), jnp.int32, one_chip),
+            _x(L, B, one_chip),
+            scale,
+            rows_per_tile=R, window=W, gather_chunk=512,
+        )
+
+
+@pytest.mark.parametrize("B", [1, 8])
+def test_sellcs_compiles_at_its_limit(one_chip, B):
+    n = WHOLE_X_MAX_COLS["sellcs"]
+    T, C, W = n // 8, 8, 128           # C-row chunks, rows capped at 128 nnz
+    _assert_mosaic(
+        spmv_sellcs_pallas,
+        _spec((T, C, W), jnp.float32, one_chip),
+        _spec((T, C, W), jnp.int32, one_chip),
+        _x(n, B, one_chip),
+        _spec((T, C, W // 128), jnp.float32, one_chip),
+        gather_chunk=512,
+    )
+
+
+@pytest.mark.parametrize("B", [1, 8])
+def test_segsum_compiles_at_its_limit(one_chip, B):
+    # powerlaw_zipf at n = 65,536: 4.9M nnz in 512-slot chunks, ≤ 120 rows each
+    n = WHOLE_X_MAX_COLS["segsum"]
+    T, S, R = 9639, 512, 120
+    _assert_mosaic(
+        spmv_segsum_pallas,
+        _spec((T, S), jnp.bfloat16, one_chip),
+        _spec((T, S), jnp.int32, one_chip),
+        _spec((T, S), jnp.int32, one_chip),
+        _x(n, B, one_chip),
+        segs_per_chunk=R, gather_chunk=512,
+    )
+
+
+@pytest.mark.parametrize("B", [1, 8])
+def test_diahybrid_compiles_at_its_limit(one_chip, B):
+    # stencil_fringe at n = 2^20 (side 1024): the nine 9-point diagonals
+    n, side, row_tile = WHOLE_X_MAX_COLS["diahybrid"], 1024, 256
+    offsets = tuple(sorted(dy * side + dx for dy in (-1, 0, 1) for dx in (-1, 0, 1)))
+    lead = -offsets[0]
+    span = -(-(offsets[-1] + lead) // 128) * 128
+    L = n + span
+    _assert_mosaic(
+        spmv_dia_pallas,
+        _spec((len(offsets), n), jnp.float32, one_chip),
+        _x(L, B, one_chip),
+        offsets=offsets, lead=lead, row_tile=row_tile,
+    )
