@@ -20,6 +20,8 @@ from typing import List, Tuple
 
 import numpy as np
 
+from repro.obs import get_registry
+
 from .formats import CSRMatrix
 
 
@@ -238,12 +240,20 @@ def bandk(csr: CSRMatrix, k: int = 3, max_coarse_ratio: float = 0.05) -> np.ndar
     ``k-1`` coarsening levels; each level ordered with weighted CM; expansion
     orders each coarse node's children by their fine-level CM rank.  Returns
     the permutation ``perm`` such that ``A[perm][:, perm]`` is banded.
+
+    Times its phases into the process registry, inside ``prepare``'s
+    ``phase.reorder``: ``phase.reorder.graph``, ``phase.reorder.coarsen``
+    (every level) and ``phase.reorder.order`` (every weighted CM, with its
+    pseudo-peripheral BFS).
     """
-    g0 = graph_from_csr(csr)
+    reg = get_registry()
+    with reg.timer("prepare", "phase.reorder.graph"):
+        g0 = graph_from_csr(csr)
     graphs = [g0]
     maps: List[np.ndarray] = []
     for _ in range(max(k - 1, 0)):
-        g, f2c = coarsen(graphs[-1])
+        with reg.timer("prepare", "phase.reorder.coarsen"):
+            g, f2c = coarsen(graphs[-1])
         if g.n >= graphs[-1].n or g.n <= max(2, int(g0.n * max_coarse_ratio)):
             graphs.append(g)
             maps.append(f2c)
@@ -253,14 +263,16 @@ def bandk(csr: CSRMatrix, k: int = 3, max_coarse_ratio: float = 0.05) -> np.ndar
 
     # order the coarsest level
     rank = np.empty(graphs[-1].n, np.int64)
-    rank[weighted_cm(graphs[-1])] = np.arange(graphs[-1].n)
+    with reg.timer("prepare", "phase.reorder.order"):
+        rank[weighted_cm(graphs[-1])] = np.arange(graphs[-1].n)
 
     # expand: children sorted by (coarse rank, fine CM rank within the level)
     for level in range(len(maps) - 1, -1, -1):
         g_fine = graphs[level]
         f2c = maps[level]
         fine_rank = np.empty(g_fine.n, np.int64)
-        fine_rank[weighted_cm(g_fine)] = np.arange(g_fine.n)
+        with reg.timer("prepare", "phase.reorder.order"):
+            fine_rank[weighted_cm(g_fine)] = np.arange(g_fine.n)
         order = np.lexsort((fine_rank, rank[f2c]))
         rank = np.empty(g_fine.n, np.int64)
         rank[order] = np.arange(g_fine.n)

@@ -24,17 +24,20 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs import concrete, get_registry
+from repro.obs import annotated, concrete, get_registry
 
 MatVec = Callable[[jax.Array], jax.Array]
 
 
-def _record_solve(name: str, iters, residuals, seconds: float) -> None:
+def _record_solve(name: str, iters, residuals, t_start: float) -> None:
     """Record one finished solve (no-op when disabled or inside a trace).
 
     ``iters`` / ``residuals`` are outputs of the solver's ``while_loop``: if
     ``iters`` is concrete the solve ran eagerly and the history is real data;
-    if it is a tracer the whole record is skipped (nothing partial).
+    if it is a tracer the whole record is skipped (nothing partial).  The
+    clock is read once ``concrete`` has waited for ``iters``, so
+    ``<name>.time_s`` is the solve from ``t_start`` to its result, not the
+    time to enqueue it.
     """
     reg = get_registry()
     if not reg.enabled:
@@ -42,6 +45,7 @@ def _record_solve(name: str, iters, residuals, seconds: float) -> None:
     k = concrete(iters)
     if k is None:
         return
+    seconds = time.perf_counter() - t_start
     import numpy as np
 
     reg.counter("solvers", f"{name}.solves")
@@ -57,6 +61,7 @@ class CGResult(NamedTuple):
     residual: jax.Array
 
 
+@annotated("repro.cg")
 def cg(
     matvec: MatVec,
     b: jax.Array,
@@ -105,7 +110,7 @@ def cg(
     x, r, _, rs, k, hist = jax.lax.while_loop(
         cond, body, (x0, r0, p0, rs0, 0, hist0)
     )
-    _record_solve("cg", k, hist, time.perf_counter() - t_start)
+    _record_solve("cg", k, hist, t_start)
     return CGResult(x=x, iters=k, residual=jnp.sqrt(rs))
 
 
@@ -175,7 +180,7 @@ def block_cg(
     X, R, _, rs, k, hist = jax.lax.while_loop(
         cond, body, (X0, R0, P0, rs0, 0, hist0)
     )
-    _record_solve("block_cg", k, hist, time.perf_counter() - t_start)
+    _record_solve("block_cg", k, hist, t_start)
     return BlockCGResult(X=X, iters=k, residual=jnp.sqrt(rs))
 
 

@@ -199,8 +199,12 @@ class PreparedSpMV:
           permutation is applied on the way in and inverted on the way out
           using device-resident index arrays cached at ``prepare`` time.
         """
-        y_new = self(x_old[self._perm_dev])
-        return y_new[self._inv_perm_dev]
+        with annotate("repro.apply_original"):
+            with annotate("repro.permute_in"):
+                x_new = x_old[self._perm_dev]
+            y_new = self(x_new)
+            with annotate("repro.permute_out"):
+                return y_new[self._inv_perm_dev]
 
     # -- introspection used by benchmarks ------------------------------------
     def overhead_fraction(self) -> float:
@@ -616,11 +620,12 @@ def prepare(
             perm = np.arange(A.m)
         else:
             raise ValueError(f"unknown reorder {reorder!r}")
-        Ar = A.symmetric_permute(perm) if reorder != "natural" else A
-        if stats is not None and reorder != "natural":
-            # report the post-reordering bandwidth (row-length stats are
-            # permutation-invariant, so the routing decision is unaffected)
-            stats = compute_stats(Ar)
+        with reg.timer("prepare", "phase.reorder.symperm"):
+            Ar = A.symmetric_permute(perm) if reorder != "natural" else A
+            if stats is not None and reorder != "natural":
+                # report the post-reordering bandwidth (row-length stats are
+                # permutation-invariant, so the routing decision is unaffected)
+                stats = compute_stats(Ar)
 
     with reg.timer("prepare", "phase.tune"):
         if params is None:

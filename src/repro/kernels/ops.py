@@ -26,7 +26,7 @@ from repro.kernels.spmv_diahybrid import spmv_dia_pallas
 from repro.kernels.spmv_ell import spmv_ell_pallas
 from repro.kernels.spmv_segsum import spmv_segsum_pallas
 from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
-from repro.obs import annotated
+from repro.obs import annotate, annotated
 
 
 def _pad_rows(x: jax.Array, target: int) -> jax.Array:
@@ -45,6 +45,14 @@ def _pad_x_to_blocks(x: jax.Array, window: int) -> jax.Array:
     n = x.shape[0]
     nblocks = -(-n // window)
     return _pad_rows(x, (nblocks + 1) * window)
+
+
+def _fold_remainder(y: jax.Array, view, x: jax.Array) -> jax.Array:
+    """Add the COO remainder (``rem_row``/``rem_col``/``rem_val``) into y."""
+    rem_val = view.rem_val.astype(y.dtype)
+    if x.ndim == 2:
+        rem_val = rem_val[:, None]
+    return y.at[view.rem_row].add(rem_val * x[view.rem_col].astype(y.dtype))
 
 
 def combine_tile_rows(parts, tile_ids, num_tiles: int, rows_per_tile: int,
@@ -86,7 +94,7 @@ def combine_tile_rows(parts, tile_ids, num_tiles: int, rows_per_tile: int,
     return out[:num_tiles].reshape((num_tiles * rows_per_tile,) + tail)
 
 
-@annotated("repro.spmv_csrk", count_section="kernels")
+@annotated("repro.spmv_csrk_tiles")
 def spmv_csrk(
     tiles: CSRkTiles,
     x: jax.Array,
@@ -100,7 +108,8 @@ def spmv_csrk(
     ``x`` may be a vector ([n]) or a multi-vector block ([n, B]); the batched
     form streams the matrix tiles once for all B right-hand sides.
     """
-    xp = _pad_x_to_blocks(x, tiles.window)
+    with annotate("repro.pad_x"):
+        xp = _pad_x_to_blocks(x, tiles.window)
     y = spmv_csrk_tiles_pallas(
         tiles.vals,
         tiles.local_col,
@@ -116,14 +125,12 @@ def spmv_csrk(
     )
     y = y[: tiles.shape[0]]
     if tiles.remainder_nnz:
-        rem_val = tiles.rem_val.astype(y.dtype)
-        if x.ndim == 2:
-            rem_val = rem_val[:, None]
-        y = y.at[tiles.rem_row].add(rem_val * x[tiles.rem_col].astype(y.dtype))
+        with annotate("repro.remainder"):
+            y = _fold_remainder(y, tiles, x)
     return y
 
 
-@annotated("repro.spmv_csrk_bucketed", count_section="kernels")
+@annotated("repro.spmv_csrk_bucketed")
 def spmv_csrk_bucketed(
     buckets: CSRkTileBuckets,
     x: jax.Array,
@@ -145,7 +152,8 @@ def spmv_csrk_bucketed(
     ``x`` may be [n] or [n, B], same as :func:`spmv_csrk`.
     """
     R = buckets.rows_per_tile
-    xp = _pad_x_to_blocks(x, buckets.window)
+    with annotate("repro.pad_x"):
+        xp = _pad_x_to_blocks(x, buckets.window)
     parts = [
         spmv_csrk_tiles_pallas(
             b.vals,
@@ -162,18 +170,17 @@ def spmv_csrk_bucketed(
         )
         for b in buckets.buckets
     ]
-    y = combine_tile_rows(
-        parts, buckets.tile_ids, buckets.num_tiles, R, dtype=x.dtype
-    )[: buckets.shape[0]]
+    with annotate("repro.combine"):
+        y = combine_tile_rows(
+            parts, buckets.tile_ids, buckets.num_tiles, R, dtype=x.dtype
+        )[: buckets.shape[0]]
     if buckets.remainder_nnz:
-        rem_val = buckets.rem_val.astype(y.dtype)
-        if x.ndim == 2:
-            rem_val = rem_val[:, None]
-        y = y.at[buckets.rem_row].add(rem_val * x[buckets.rem_col].astype(y.dtype))
+        with annotate("repro.remainder"):
+            y = _fold_remainder(y, buckets, x)
     return y
 
 
-@annotated("repro.spmv_sellcs", count_section="kernels")
+@annotated("repro.spmv_sellcs_tiles")
 def spmv_sellcs(
     tiles: SELLCSTiles,
     x: jax.Array,
@@ -206,7 +213,7 @@ def spmv_sellcs(
     return out.at[tiles.row_perm].set(y_sorted)[:m]
 
 
-@annotated("repro.spmv_segsum", count_section="kernels")
+@annotated("repro.spmv_segsum_csr")
 def spmv_segsum(
     mat: SegSumCSR,
     x: jax.Array,
@@ -242,7 +249,7 @@ def spmv_segsum(
     return out.at[mat.seg_row.reshape(-1)].add(partial)[:m]
 
 
-@annotated("repro.spmv_diahybrid", count_section="kernels")
+@annotated("repro.spmv_diahybrid")
 def spmv_diahybrid(
     mat: DIAHybridMatrix,
     x: jax.Array,
@@ -290,7 +297,7 @@ def spmv_diahybrid(
     return y
 
 
-@annotated("repro.spmv_ell", count_section="kernels")
+@annotated("repro.spmv_ell")
 def spmv_ell(mat: ELLMatrix, x: jax.Array, *, row_tile: int = 256,
              interpret: bool | None = None):
     """ELL SpMV via the Pallas baseline kernel (rows padded to the tile)."""

@@ -49,7 +49,7 @@ def spmv_coo(mat: COOMatrix, x: jax.Array) -> jax.Array:
     return jnp.zeros((mat.shape[0],), contrib.dtype).at[mat.row_idx].add(contrib)
 
 
-@annotated("repro.oracle.spmv_csr", count_section="oracles")
+@annotated("repro.oracle.spmv_csr")
 def spmv_csr(mat: CSRMatrix, x: jax.Array) -> jax.Array:
     """Row-segmented CSR SpMV — the canonical oracle."""
     rows = jnp.repeat(
@@ -109,7 +109,7 @@ def spmv_bcsr(mat: BCSRMatrix, x: jax.Array) -> jax.Array:
     return yb.reshape(-1)[: mat.shape[0]]
 
 
-@annotated("repro.oracle.spmv_csrk_tiles", count_section="oracles")
+@annotated("repro.oracle.spmv_csrk_tiles")
 def spmv_csrk_tiles(tiles: CSRkTiles, x: jax.Array) -> jax.Array:
     """Oracle for the padded-tile view consumed by the Pallas kernel.
 
@@ -143,7 +143,7 @@ def spmv_csrk_tiles(tiles: CSRkTiles, x: jax.Array) -> jax.Array:
     return y
 
 
-@annotated("repro.oracle.spmv_csrk_buckets", count_section="oracles")
+@annotated("repro.oracle.spmv_csrk_buckets")
 def spmv_csrk_buckets(buckets: CSRkTileBuckets, x: jax.Array) -> jax.Array:
     """Oracle for the slot-bucketed tile view: per-bucket tile oracle runs,
     scattered back to global tile rows, COO remainder folded once."""
@@ -162,7 +162,7 @@ def spmv_csrk_buckets(buckets: CSRkTileBuckets, x: jax.Array) -> jax.Array:
     return y
 
 
-@annotated("repro.oracle.spmv_sellcs_tiles", count_section="oracles")
+@annotated("repro.oracle.spmv_sellcs_tiles")
 def spmv_sellcs_tiles(tiles: SELLCSTiles, x: jax.Array) -> jax.Array:
     """Oracle for the uniform-width SELL-C-σ Pallas view (value-dtype aware).
 
@@ -184,7 +184,7 @@ def spmv_sellcs_tiles(tiles: SELLCSTiles, x: jax.Array) -> jax.Array:
     return out.at[tiles.row_perm].set(y_sorted)[:m]
 
 
-@annotated("repro.oracle.spmv_sellcs", count_section="oracles")
+@annotated("repro.oracle.spmv_sellcs_slots")
 def spmv_sellcs(mat: SELLCSMatrix, x: jax.Array) -> jax.Array:
     """SELL-C-σ SpMV oracle over the canonical flat slot arrays.
 
@@ -209,7 +209,7 @@ def spmv_sellcs(mat: SELLCSMatrix, x: jax.Array) -> jax.Array:
     return out.at[mat.row_perm].set(y_sorted)[:m]
 
 
-@annotated("repro.oracle.spmv_segsum", count_section="oracles")
+@annotated("repro.oracle.spmv_segsum_csr")
 def spmv_segsum(mat: SegSumCSR, x: jax.Array) -> jax.Array:
     """Speculative segmented-sum oracle (value-dtype aware).
 
@@ -269,7 +269,7 @@ def _dia_plane(mat: DIAHybridMatrix, x: jax.Array) -> jax.Array:
     return jnp.sum(contrib, axis=0).astype(x.dtype)
 
 
-@annotated("repro.oracle.spmv_diahybrid", count_section="oracles")
+@annotated("repro.oracle.spmv_diahybrid")
 def spmv_diahybrid(mat: DIAHybridMatrix, x: jax.Array) -> jax.Array:
     """Partially-diagonal hybrid oracle: shifted-slice DIA contraction plus
     the CSR remainder through the canonical CSR oracle — the same two-part
@@ -285,7 +285,7 @@ def spmv_diahybrid(mat: DIAHybridMatrix, x: jax.Array) -> jax.Array:
     return y
 
 
-@annotated("repro.oracle.spmm_csr", count_section="oracles")
+@annotated("repro.oracle.spmm_csr")
 def spmm_csr(mat: CSRMatrix, X: jax.Array) -> jax.Array:
     """SpMM oracle (multi-vector SpMV), used by the CG block solver."""
     rows = jnp.repeat(

@@ -2,8 +2,8 @@
 
 One process-global :class:`MetricsRegistry` (swap it with
 :func:`set_registry` / :func:`using_registry`) accumulates everything the
-instrumented layers emit — ``prepare()`` phase timings, kernel launch
-counters, solver residual series, sharding decisions — and exports them as
+instrumented layers emit — ``prepare()`` phase timings, solver residual
+series, sharding decisions — and exports them as
 the same ``{"section", "name", "value", "unit"}`` records the benchmark
 harness already archives, so telemetry and perf tracking share one schema.
 
@@ -124,7 +124,8 @@ class MetricsRegistry:
             self._gauges[(section, name)] = (v, unit)
 
     def timer(self, section: str, name: str):
-        """Context manager timing its block into a running aggregate.
+        """Context manager timing its block into a running aggregate, inside
+        the host span ``<section>.<name>`` of a profiler capture.
 
         When the registry is disabled this returns one shared null context —
         no clock is read and nothing is allocated.
@@ -213,9 +214,14 @@ class MetricsRegistry:
 
 
 class _TimerCtx:
-    """Re-entrant-per-use timing context feeding one registry aggregate."""
+    """Re-entrant-per-use timing context feeding one registry aggregate.
 
-    __slots__ = ("_reg", "_section", "_name", "_t0")
+    The block also runs inside the host span ``<section>.<name>``
+    (``jax.profiler.TraceAnnotation``), so a timed phase shows under the
+    same name, on the trace's clock, in any profiler capture around it.
+    """
+
+    __slots__ = ("_reg", "_section", "_name", "_t0", "_span")
 
     def __init__(self, reg: MetricsRegistry, section: str, name: str):
         self._reg = reg
@@ -223,13 +229,17 @@ class _TimerCtx:
         self._name = name
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._span = TraceAnnotation(f"{self._section}.{self._name}")
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._reg._add_timing(
-            self._section, self._name, time.perf_counter() - self._t0
-        )
+        seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._reg._add_timing(self._section, self._name, seconds)
         return False
 
 

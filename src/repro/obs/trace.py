@@ -1,13 +1,22 @@
 """Trace annotations: name regions of the sparse stack for profilers.
 
-:func:`annotate` is the single spelling every layer uses.  It stacks two
+:func:`annotate` is the single spelling every layer uses for a region of a
+call; a registry timer (:meth:`MetricsRegistry.timer`) is the spelling for a
+set-up phase, and opens the same host span.  ``annotate`` stacks two
 complementary scopes:
 
-* ``jax.named_scope`` — tags the *traced* HLO, so kernel launches show up
-  under readable names in compiled-module dumps and XLA profiles;
-* ``jax.profiler.TraceAnnotation`` — tags the *host* timeline, so the
-  setup-side phases (``prepare()``, tile builds, uploads) are visible in a
+* ``jax.named_scope`` — tags the *traced* HLO: under ``jit`` every device
+  operation carries the scope path in its op_name, so a profile can charge
+  it to the region that emitted it;
+* ``jax.profiler.TraceAnnotation`` — tags the *host* timeline, so eager
+  launches and host work show under the region's name in a
   ``jax.profiler.trace()`` capture next to the device stream.
+
+A region name never holds a Pallas kernel's name (``spmv_csrk``,
+``spmv_sellcs``, ``spmv_segsum``, ``spmv_dia``) as a whole word: a trace
+reader that finds kernels by name would count the region's glue as kernel
+time.  docs/observability.md lists every region with the metric that reads
+it.
 
 Neither scope changes any computed value; when telemetry is disabled the
 function returns one shared null context and touches nothing.
@@ -17,7 +26,16 @@ from __future__ import annotations
 import contextlib
 import functools
 
+import jax
+
 from repro.obs.registry import _NULL_CTX, get_registry
+
+# Under ``jit`` a region's name lives only in the compiled program's op_name
+# metadata.  JAX's persistent compilation cache leaves metadata out of its
+# key, so it would hand back a program compiled with other region names (the
+# same computation from another version of this library): key it with the
+# metadata as well.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def annotate(name: str):
@@ -25,38 +43,25 @@ def annotate(name: str):
 
     Usage::
 
-        with annotate("repro.spmv_csrk"):
-            y = spmv_csrk_tiles_pallas(...)
+        with annotate("repro.permute_in"):
+            x_new = x_old[perm]
 
     Returns a shared null context when telemetry is disabled (no-op).
     """
     if not get_registry().enabled:
         return _NULL_CTX
-    import jax
-
     ctx = contextlib.ExitStack()
     ctx.enter_context(jax.profiler.TraceAnnotation(name))
     ctx.enter_context(jax.named_scope(name))
     return ctx
 
 
-def annotated(name: str, *, count_section: str | None = None):
-    """Decorator form of :func:`annotate`, optionally counting invocations.
-
-    ``count_section`` additionally bumps a ``<name>.calls`` counter in that
-    section.  The counter counts *Python-level* invocations: under ``jit``
-    that is trace events (once per compilation), not per-step executions —
-    exactly the quantity that tells you whether a wrapper is retracing.
-    """
+def annotated(name: str):
+    """Decorator form of :func:`annotate`: the whole call is the region."""
 
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            reg = get_registry()
-            if not reg.enabled:
-                return fn(*args, **kwargs)
-            if count_section is not None:
-                reg.counter(count_section, f"{name}.calls")
             with annotate(name):
                 return fn(*args, **kwargs)
 

@@ -205,6 +205,50 @@ def test_cg_emits_residual_series_eagerly(rng):
         assert reg.get_series("solvers", "cg.time_s")[0] > 0
 
 
+def test_cg_time_s_covers_the_solve_not_its_enqueue(rng):
+    """Each matvec sleeps on the host while the loop runs, so the solve lasts
+    at least iterations x the sleep; ``time_s`` must cover it, and no more
+    than the caller's wall time until the result is ready."""
+    A, op = _spd_op()
+    pause = 0.004
+
+    def slow(v):
+        def host(a):
+            time.sleep(pause)
+            return np.asarray(a)
+
+        return op(jax.pure_callback(host, jax.ShapeDtypeStruct(v.shape, v.dtype), v))
+
+    b = jnp.asarray(rng.standard_normal(A.n), jnp.float32)
+    cg(slow, b, maxiter=8)                              # compile outside the timing
+    with using_registry(MetricsRegistry()) as reg:
+        t0 = time.perf_counter()
+        res = cg(slow, b, maxiter=8)
+        jax.block_until_ready(res)
+        wall = time.perf_counter() - t0
+        time_s = reg.get_series("solvers", "cg.time_s")[0]
+    assert int(res.iters) * pause <= time_s <= wall
+
+
+def test_record_solve_reads_the_clock_once_the_result_is_ready():
+    """A result still running on the device when the solver returns: the
+    recorded time is at least its blocked wall time, not its dispatch."""
+    from repro.core.solvers import _record_solve
+
+    a = jnp.ones((1500, 1500), jnp.float32)
+    heavy = jax.jit(lambda a: jnp.sum(jnp.sin(a) @ jnp.cos(a) @ jnp.sin(a)) * 0.0 + 3.0)
+    jax.block_until_ready(heavy(a))
+    t0 = time.perf_counter()
+    jax.block_until_ready(heavy(a))
+    blocked = time.perf_counter() - t0
+    with using_registry(MetricsRegistry()) as reg:
+        t_start = time.perf_counter()
+        iters = heavy(a)                                   # dispatched, still running
+        _record_solve("probe", iters, np.zeros(3), t_start)
+        assert reg.get_series("solvers", "probe.iters") == [3.0]
+        assert reg.get_series("solvers", "probe.time_s")[0] >= 0.25 * blocked
+
+
 def test_block_cg_emits_worst_column_series(rng):
     A, op = _spd_op()
     B = jnp.asarray(rng.standard_normal((A.n, 4)), jnp.float32)
@@ -235,6 +279,97 @@ def test_bit_for_bit_with_telemetry_on_vs_off(rng, fmt):
 
     assert np.array_equal(y_on, y_off)       # bit-for-bit, not allclose
     assert np.array_equal(cg_on, cg_off)
+
+
+@pytest.mark.parametrize("layout", ["bucketed", "monolithic"])
+def test_apply_original_bit_for_bit_with_spans_on_vs_off(rng, layout):
+    """The operator-call spans change no bit, eager or under ``jit``."""
+    A = grid_laplacian_2d(16, 16)
+    x = jnp.asarray(rng.standard_normal(A.n), jnp.float32)
+    out = {}
+    for on in (True, False):
+        with using_registry(MetricsRegistry(enabled=on)):
+            op = prepare(A, device="tpu_v5e", format="csrk", tile_layout=layout)
+            out[on] = (np.asarray(op.apply_original(x)),
+                       np.asarray(jax.jit(op.apply_original)(x)))
+    assert np.array_equal(out[True][0], out[False][0])
+    assert np.array_equal(out[True][1], out[False][1])
+
+
+# --- spans in a profiler capture -----------------------------------------------
+
+
+def _host_spans(log_dir, prefix):
+    """(name, start_ns, end_ns) of every host event whose name starts with prefix."""
+    from jax.profiler import ProfileData
+
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(log_dir) for f in fs
+             if f.endswith(".xplane.pb")]
+    data = ProfileData.from_file(paths[0])
+    return sorted((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for p in data.planes if p.name == "/host:CPU"
+                  for ln in p.lines for e in ln.events if e.name.startswith(prefix))
+
+
+def test_apply_original_spans_nest_in_order_in_a_capture(rng, tmp_path):
+    A = grid_laplacian_2d(16, 16)
+    with using_registry(MetricsRegistry()):
+        op = prepare(A, device="tpu_v5e", format="csrk")
+        assert op.tile_buckets is not None
+        x = jnp.asarray(rng.standard_normal(A.n), jnp.float32)
+        op.apply_original(x).block_until_ready()
+        jax.profiler.start_trace(str(tmp_path))
+        op.apply_original(x).block_until_ready()
+        jax.profiler.stop_trace()
+    spans = sorted(_host_spans(str(tmp_path), "repro."), key=lambda s: (s[1], -s[2]))
+    names = [s[0] for s in spans]
+    want = ["repro.apply_original", "repro.permute_in", "repro.spmv_csrk_bucketed",
+            "repro.pad_x", "repro.combine"]
+    if op.tile_buckets.remainder_nnz:
+        want.append("repro.remainder")
+    assert names == want + ["repro.permute_out"]
+    by = dict((s[0], s[1:]) for s in spans)
+
+    def inside(child, parent):
+        return by[parent][0] <= by[child][0] and by[child][1] <= by[parent][1]
+
+    for child in names[1:]:
+        assert inside(child, "repro.apply_original"), child
+    for child in want[3:]:
+        assert inside(child, "repro.spmv_csrk_bucketed"), child
+    assert by["repro.permute_in"][1] <= by["repro.spmv_csrk_bucketed"][0]
+    assert by["repro.spmv_csrk_bucketed"][1] <= by["repro.permute_out"][0]
+
+
+def test_the_compilation_cache_keys_programs_with_their_region_names():
+    # a program compiled under other scope names must not come back from the cache
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+def test_registry_timer_opens_a_host_span(tmp_path):
+    reg = MetricsRegistry()
+    jax.profiler.start_trace(str(tmp_path))
+    with reg.timer("prepare", "phase.demo"):
+        time.sleep(0.002)
+    jax.profiler.stop_trace()
+    (name, start, end), = _host_spans(str(tmp_path), "prepare.")
+    assert name == "prepare.phase.demo"
+    assert (end - start) * 1e-9 == pytest.approx(
+        reg.records()[0]["value"] * 1e-3, rel=0.5)
+
+
+def test_bandk_phase_timers_sum_within_the_reorder_phase():
+    A = grid_laplacian_2d(24, 24)
+    with using_registry(MetricsRegistry()) as reg:
+        prepare(A, device="tpu_v5e", format="auto")
+        ms = {r["name"]: r["value"] for r in reg.records() if r["section"] == "prepare"}
+    parts = ["phase.reorder.graph", "phase.reorder.coarsen", "phase.reorder.order",
+             "phase.reorder.symperm"]
+    for part in parts:
+        assert ms[f"{part}_calls"] >= 1, part
+    assert ms["phase.reorder.coarsen_calls"] == 2          # k = 3: two levels
+    assert ms["phase.reorder.order_calls"] == 3            # every level ordered
+    assert 0 < sum(ms[f"{p}_ms"] for p in parts) <= ms["phase.reorder_ms"]
 
 
 # --- metadata / export / trajectory / gate -----------------------------------
