@@ -96,7 +96,7 @@ def sweep_gather_chunk(scale: int) -> int:
         t = time_fn(
             lambda v, c=chunk: spmv_csrk_tiles_pallas(
                 tiles.vals, tiles.local_col, tiles.local_row,
-                tiles.win_block, v, tiles.val_scale,
+                tiles.win_block, tiles.col_blocks, v, tiles.val_scale,
                 rows_per_tile=tiles.rows_per_tile, window=W,
                 gather_chunk=c,
             ),

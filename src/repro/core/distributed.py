@@ -628,7 +628,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
     returned callable accepts x of shape [n] or [n, B].
 
     ``shard_arrays`` layouts (all stacked [D, ...]):
-      csrk blocking: ``vals/lcol/lrow/win`` (+ ``scale``);
+      csrk blocking: ``vals/lcol/lrow/win/cblk`` (+ ``scale``);
       csrk overlap: ``i_*``/``b_*`` subset stacks + ``i_ids``/``b_ids``;
       sellcs blocking: ``vals/cols`` (+ ``scale``);
       sellcs overlap: ``i_vals/i_cols/i_ids`` and ``b_*`` counterparts.
@@ -707,9 +707,9 @@ def _build_plan_call(op: ShardedPreparedSpMV):
         chunk = base.params.gather_chunk
         has_scale = "scale" in arrs or "i_scale" in arrs
 
-        def launch(v, lc, lr, wb, xp, sc):
+        def launch(v, lc, lr, wb, cb, xp, sc):
             return spmv_csrk_tiles_pallas(
-                v, lc, lr, wb, xp, sc,
+                v, lc, lr, wb, cb, xp, sc,
                 rows_per_tile=R, window=W, gather_chunk=chunk,
                 gather_mode=gather_mode, interpret=interpret,
             )
@@ -717,8 +717,8 @@ def _build_plan_call(op: ShardedPreparedSpMV):
         if plan.overlap:
             Tp = plan.tiles_per_shard
             names = [
-                "i_vals", "i_lcol", "i_lrow", "i_win", "i_ids",
-                "b_vals", "b_lcol", "b_lrow", "b_win", "b_ids",
+                "i_vals", "i_lcol", "i_lrow", "i_win", "i_cblk", "i_ids",
+                "b_vals", "b_lcol", "b_lrow", "b_win", "b_cblk", "b_ids",
             ]
             if has_scale:
                 names += ["i_scale", "b_scale"]
@@ -731,14 +731,14 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                 # phase 2: interior tiles read only the local x slice
                 y_int = launch(
                     a["i_vals"][0], a["i_lcol"][0], a["i_lrow"][0],
-                    a["i_win"][0], paste(xs, 0, Lp),
+                    a["i_win"][0], a["i_cblk"][0], paste(xs, 0, Lp),
                     a["i_scale"][0] if has_scale else None,
                 )
                 # phase 3: boundary tiles consume the received halo window
                 xw = paste(jnp.concatenate([left, xs, right]), H, Lp)
                 y_bnd = launch(
                     a["b_vals"][0], a["b_lcol"][0], a["b_lrow"][0],
-                    a["b_win"][0], xw,
+                    a["b_win"][0], a["b_cblk"][0], xw,
                     a["b_scale"][0] if has_scale else None,
                 )
                 return combine_tile_rows(
@@ -747,7 +747,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                 )
 
         else:
-            names = ["vals", "lcol", "lrow", "win"]
+            names = ["vals", "lcol", "lrow", "win", "cblk"]
             if has_scale:
                 names += ["scale"]
 
@@ -756,7 +756,7 @@ def _build_plan_call(op: ShardedPreparedSpMV):
                 xp = distribute_x(args[-1], Lp)
                 return launch(
                     a["vals"][0], a["lcol"][0], a["lrow"][0], a["win"][0],
-                    xp, a["scale"][0] if has_scale else None,
+                    a["cblk"][0], xp, a["scale"][0] if has_scale else None,
                 )
 
         f = jax.shard_map(
@@ -1071,6 +1071,7 @@ def shard_prepared(
         lr = np.asarray(tiles.local_row)
         wb = np.asarray(tiles.win_block)
         scale = None if tiles.val_scale is None else np.asarray(tiles.val_scale)
+        cblk = np.asarray(tiles.col_blocks)
         if overlap:
             Ti, Tb = plan.num_interior, plan.num_boundary
             for key, ids, T_sub in (("i", interior_ids, Ti),
@@ -1079,6 +1080,7 @@ def shard_prepared(
                 arrs[f"{key}_lcol"] = _stack_tile_subset(lc, ids, D, Tp, T_sub)
                 arrs[f"{key}_lrow"] = _stack_tile_subset(lr, ids, D, Tp, T_sub)
                 arrs[f"{key}_win"] = _stack_tile_subset(wb, ids, D, Tp, T_sub)
+                arrs[f"{key}_cblk"] = _stack_tile_subset(cblk, ids, D, Tp, T_sub)
                 arrs[f"{key}_ids"] = _stack_subset_ids(ids, D, Tp, T_sub)
                 if scale is not None:
                     arrs[f"{key}_scale"] = _stack_tile_subset(
@@ -1089,6 +1091,7 @@ def shard_prepared(
             arrs["lcol"] = _stack_shards(lc, D, Tp)
             arrs["lrow"] = _stack_shards(lr, D, Tp)
             arrs["win"] = _stack_shards(wb, D, Tp)
+            arrs["cblk"] = _stack_shards(cblk, D, Tp)
             if scale is not None:
                 arrs["scale"] = _stack_shards(scale, D, Tp)
     elif base.backend == "sellcs":

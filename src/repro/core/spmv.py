@@ -52,7 +52,7 @@ from repro.sparse import (
 )
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
-from repro.kernels.gather import WHOLE_X_MAX_COLS, resolve_interpret
+from repro.kernels.gather import WHOLE_X_MAX_COLS, pick_chunk, resolve_interpret
 from repro.obs import annotate, get_registry
 
 
@@ -293,7 +293,8 @@ def _record_prepared(op: PreparedSpMV) -> PreparedSpMV:
                        op.dia.remainder.col_idx)
         elif op.tiles is not None:
             uploads = (op.tiles.vals, op.tiles.local_col,
-                       op.tiles.local_row, op.tiles.win_block)
+                       op.tiles.local_row, op.tiles.win_block,
+                       op.tiles.col_blocks)
         else:
             uploads = (op.csrk.csr.vals, op.csrk.csr.col_idx)
         for arr in uploads + (op._perm_dev, op._inv_perm_dev):
@@ -312,6 +313,14 @@ def _record_prepared(op: PreparedSpMV) -> PreparedSpMV:
     else:
         tile_count = op.tiles.num_tiles if op.tiles is not None else 0
     reg.gauge("prepare", "tile_count", tile_count, unit="count")
+    if op.backend == "csrk" and op.tiles is not None:
+        # one-hot chunks the CSR-k kernel visits, as a share of a full sweep
+        # of every tile's 2·window (bucketing keeps each tile's table row)
+        chunk = pick_chunk(op.tiles.window, op.params.gather_chunk)
+        sweep = op.tiles.num_tiles * (2 * op.tiles.window // chunk)
+        reg.gauge("prepare", "csrk.onehot_share",
+                  100.0 * op.tiles.chunks_visited(chunk).sum() / max(sweep, 1),
+                  unit="%")
     if op.stats is not None:
         reg.gauge("prepare", "stats.row_var", op.stats.row_var)
         reg.gauge("prepare", "stats.bandwidth", op.stats.bandwidth,

@@ -163,9 +163,32 @@ def dequant(vals: jax.Array, scale) -> jax.Array:
     )
 
 
+def _onehot_dot(xs: jax.Array, lc: jax.Array, col0, dot_dtype) -> jax.Array:
+    """One one-hot pass: ``xs [P·B, chunk]`` holds x columns ``[col0, col0 +
+    chunk)``; returns ``[P·B, S]`` with each slot's term where ``lc`` falls
+    in the chunk and exact zeros elsewhere."""
+    chunk, S = xs.shape[1], lc.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, S), 0) + col0
+    onehot = (cols == lc).astype(dot_dtype)                        # [chunk, S]
+    return jnp.dot(xs.astype(dot_dtype), onehot, precision=ONE_PASS,
+                   preferred_element_type=jnp.float32)
+
+
+def _sum_parts(g: jax.Array, parts: int) -> jax.Array:
+    """``[P·B, S]`` gathered terms → ``[B, S]``: x rebuilt from its P terms."""
+    B = g.shape[0] // parts
+    out = g[:B]
+    for p in range(1, parts):
+        out = out + g[p * B:(p + 1) * B]
+    return out
+
+
 def gather(x_ref, lc: jax.Array, *, base: int, chunk: int, parts: int,
            dot_dtype):
     """``x[:, lc − base]`` for one tile, as chunked one-hot matmuls.
+
+    Every chunk of the ref is swept, whatever columns the tile holds: the
+    whole-x path of SELL-C-σ and segsum, whose columns may lie anywhere.
 
     Args:
       x_ref: ``[P·B, W]`` ref of the x columns ``[base, base + W)``, in the
@@ -184,20 +207,60 @@ def gather(x_ref, lc: jax.Array, *, base: int, chunk: int, parts: int,
 
     def body(i, acc):
         c0 = pl.multiple_of(i * chunk, LANE)
-        xs = x_ref[:, pl.ds(c0, chunk)].astype(dot_dtype)          # [P·B, chunk]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, S), 0) + (base + c0)
-        onehot = (cols == lc).astype(dot_dtype)                    # [chunk, S]
-        return acc + jnp.dot(xs, onehot, precision=ONE_PASS,
-                             preferred_element_type=jnp.float32)
+        return acc + _onehot_dot(x_ref[:, pl.ds(c0, chunk)], lc, base + c0,
+                                 dot_dtype)
 
     g = jax.lax.fori_loop(
         0, W // chunk, body, jnp.zeros((PB, S), jnp.float32)
     )
-    B = PB // parts
-    out = g[:B]
-    for p in range(1, parts):
-        out = out + g[p * B:(p + 1) * B]
-    return out
+    return _sum_parts(g, parts)
+
+
+def gather_listed(x_refs, lc: jax.Array, blocks_ref, tile, *, chunk: int,
+                  parts: int, dot_dtype):
+    """``x[:, lc]`` for one tile, visiting only the chunks its columns use.
+
+    The CSR-k path: a tile reads a banded window, and most of its chunks
+    hold none of its columns.  Those would add exact zeros, so skipping
+    them leaves every slot's value bit for bit as :func:`gather` gives it.
+
+    Args:
+      x_refs: ``[P·B, W]`` refs of consecutive x blocks; together they hold
+        columns ``[0, len(x_refs)·W)`` of ``lc``'s index space.
+      lc: ``[1, S]`` int32 column indices on lanes.
+      blocks_ref / tile: row ``tile`` of a
+        :attr:`~repro.sparse.csrk.CSRkTiles.col_blocks` table — a count,
+        then that many ascending 128-column blocks holding the tile's real
+        columns.  A chunk is visited once if any of its blocks is listed.
+      chunk, parts, dot_dtype: as for :func:`gather`.
+
+    Returns:
+      ``[B, S]`` f32.  A slot whose column lies in no listed block (a
+      padding slot) gathers 0.
+    """
+    PB, W = x_refs[0].shape
+    S = lc.shape[1]
+    per, nq = chunk // LANE, W // chunk
+
+    def visit(q, acc):
+        ref = q // nq
+        c0 = pl.multiple_of((q - ref * nq) * chunk, LANE)
+        xs = x_refs[0][:, pl.ds(c0, chunk)]
+        for k, r in enumerate(x_refs[1:], 1):
+            xs = jnp.where(ref == k, r[:, pl.ds(c0, chunk)], xs)
+        return acc + _onehot_dot(xs, lc, q * chunk, dot_dtype)
+
+    def body(i, carry):
+        acc, prev = carry
+        q = blocks_ref[tile, 1 + i] // per
+        acc = jax.lax.cond(q != prev, visit, lambda q, a: a, q, acc)
+        return acc, q
+
+    g, _ = jax.lax.fori_loop(
+        0, blocks_ref[tile, 0], body,
+        (jnp.zeros((PB, S), jnp.float32), jnp.int32(-1)),
+    )
+    return _sum_parts(g, parts)
 
 
 def gather_take(x_refs, lc: jax.Array, parts: int) -> jax.Array:
@@ -208,12 +271,7 @@ def gather_take(x_refs, lc: jax.Array, parts: int) -> jax.Array:
     (``prepare`` refuses it on a TPU).
     """
     xw = jnp.concatenate([r[...] for r in x_refs], axis=1).astype(jnp.float32)
-    g = jnp.take(xw, lc[0], axis=1)                                # [P·B, S]
-    B = g.shape[0] // parts
-    out = g[:B]
-    for p in range(1, parts):
-        out = out + g[p * B:(p + 1) * B]
-    return out
+    return _sum_parts(jnp.take(xw, lc[0], axis=1), parts)
 
 
 def reduce_rows(contrib: jax.Array, lr: jax.Array, rows: int, dot_dtype):
@@ -242,14 +300,20 @@ def reduce_rows(contrib: jax.Array, lr: jax.Array, rows: int, dot_dtype):
 
 
 def tile_rows(v, lc, lr, x_refs, bases, *, rows, chunk, parts, gather_mode,
-              dot_dtype):
+              dot_dtype, blocks=None):
     """One tile end to end: ``Σ_s v[s]·x[:, lc[s]]`` into rows ``lr``.
 
     ``x_refs``/``bases`` cover the tile's column range in order; returns
-    ``[B, rows]`` f32.
+    ``[B, rows]`` f32.  ``blocks`` — ``(table_ref, tile)`` of a
+    ``col_blocks`` table, for consecutive refs starting at base 0 — limits
+    the one-hot gather to the chunks it lists (:func:`gather_listed`);
+    without it every chunk is swept.
     """
     if gather_mode == "take":
         g = gather_take(x_refs, lc, parts)
+    elif blocks is not None:
+        g = gather_listed(x_refs, lc, *blocks, chunk=chunk, parts=parts,
+                          dot_dtype=dot_dtype)
     else:
         g = gather(x_refs[0], lc, base=bases[0], chunk=chunk, parts=parts,
                    dot_dtype=dot_dtype)

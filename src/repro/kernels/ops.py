@@ -115,6 +115,7 @@ def spmv_csrk(
         tiles.local_col,
         tiles.local_row,
         tiles.win_block,
+        tiles.col_blocks,
         xp,
         tiles.val_scale,
         rows_per_tile=tiles.rows_per_tile,
@@ -160,6 +161,7 @@ def spmv_csrk_bucketed(
             b.local_col,
             b.local_row,
             b.win_block,
+            b.col_blocks,
             xp,
             b.val_scale,
             rows_per_tile=R,

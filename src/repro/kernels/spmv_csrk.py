@@ -8,6 +8,8 @@ Mapping (DESIGN §2):
   * x[col_idx] gather    → contiguous banded x-window per tile (two adjacent
                            blocks of ``window`` columns, placed by a
                            scalar-prefetch index map) + one-hot MXU gather
+                           over only the window chunks the tile's columns
+                           fall in (its ``col_blocks`` row, read from SMEM)
   * rows                 → one-hot MXU reduce into a lane-dense ``[B, R]``
                            output block per tile
 
@@ -43,6 +45,7 @@ TILES_PER_STEP = 8
 
 def _kernel(
     win_ref,       # scalar prefetch: [T_pad] int32 window block per tile
+    blocks_ref,    # [TB, 1+K] int32 in SMEM: each tile's col_blocks row
     vals_ref,      # [TB, S]
     lc_ref,        # [TB, S]
     lr_ref,        # [TB, S]
@@ -68,6 +71,7 @@ def _kernel(
             x_refs[2 * j:2 * j + 2], (0, window),
             rows=rows, chunk=chunk, parts=parts, gather_mode=gather_mode,
             dot_dtype=dot_dtype,
+            blocks=(blocks_ref, j),
         )
         y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
 
@@ -81,6 +85,7 @@ def spmv_csrk_tiles_pallas(
     local_col: jax.Array,  # [T, S]
     local_row: jax.Array,  # [T, S]
     win_block: jax.Array,  # [T]
+    col_blocks: jax.Array,  # [T, 1 + K]
     x_padded: jax.Array,   # [(nblocks+1) * window] or [..., B] — padded by ops.py
     val_scale: jax.Array | None = None,  # [T, S/group] f32, int8 values only
     *,
@@ -96,6 +101,9 @@ def spmv_csrk_tiles_pallas(
       vals / local_col / local_row: [T, S] padded per-SSR tile arrays.
         ``vals`` may be f32, bf16, or int8; int8 requires ``val_scale``.
       win_block: [T] x-window block index per tile (scalar-prefetched).
+      col_blocks: [T, 1 + K] int32, the 128-column window blocks each
+        tile's real slots read (:attr:`CSRkTiles.col_blocks`); a tile's
+        one-hot gather visits only the chunks holding a listed block.
       x_padded: [(nblocks+1)·window] vector or [·, B] block, padded by
         ops.py (or by the distributed layer's per-shard x reconstruction).
       val_scale: optional [T, S/group] f32 per-group scales for int8 values
@@ -128,8 +136,12 @@ def spmv_csrk_tiles_pallas(
     chunk = pick_chunk(window, gather_chunk)
 
     tile_spec = pl.BlockSpec((TB, S), lambda t, w: (t, 0))
-    in_specs = [tile_spec] * 3
-    operands = [vals, local_col, local_row]
+    # every row of the last step's table block is real or zero: a zero count
+    # visits nothing, where a partial block's unwritten rows could hold any
+    in_specs = [pl.BlockSpec((TB, col_blocks.shape[1]), lambda t, w: (t, 0),
+                             memory_space=pltpu.SMEM)] + [tile_spec] * 3
+    operands = [jnp.pad(col_blocks, ((0, steps * TB - T), (0, 0))),
+                vals, local_col, local_row]
     if val_scale is not None:
         in_specs.append(pl.BlockSpec((TB, val_scale.shape[1]), lambda t, w: (t, 0)))
         operands.append(val_scale)

@@ -177,6 +177,15 @@ class CSRkTiles:
     numerically inert. Entries outside the window are diverted to a COO
     remainder (empty after Band-k on all suites).
 
+    ``col_blocks`` lists, per tile, the 128-column blocks of its 2-block
+    window that its real slots read: ``[T, 1 + K]`` int32, a count, then
+    that many block indices in ascending order (zeros past the count; K is
+    the most any tile needs).  The kernel's one-hot gather visits only the
+    chunks holding a listed block, so its work follows the tile's columns,
+    not the window width.  Real slots are the first ``tile_nnz`` — an
+    explicitly stored zero counts, so ``0·x`` still meets its x; padding
+    slots do not.  A zero row (a padding tile) visits nothing.
+
     ``value_dtype`` selects how ``vals`` is stored: ``"f32"`` (as built),
     ``"bf16"`` (half the value bytes, exact codes for the suite's small-int
     stencil weights), or ``"int8"`` with per-group symmetric scales in
@@ -202,12 +211,13 @@ class CSRkTiles:
     val_scale: Any = None      # [T, slots/INT8_GROUP] f32, int8 path only
     tile_nnz: Any = None       # [T] int32 real in-window entries per tile
     value_dtype: str = "f32"
+    col_blocks: Any = None     # [T, 1+K] int32 count + window blocks read
 
     def tree_flatten(self):
         return (
             (self.vals, self.local_col, self.local_row, self.win_block,
              self.rem_row, self.rem_col, self.rem_val, self.val_scale,
-             self.tile_nnz),
+             self.tile_nnz, self.col_blocks),
             (self.shape, self.rows_per_tile, self.window, self.value_dtype),
         )
 
@@ -215,7 +225,7 @@ class CSRkTiles:
     def tree_unflatten(cls, aux, children):
         return cls(*children[:7], shape=aux[0], rows_per_tile=aux[1],
                    window=aux[2], val_scale=children[7], tile_nnz=children[8],
-                   value_dtype=aux[3])
+                   value_dtype=aux[3], col_blocks=children[9])
 
     @property
     def num_tiles(self) -> int:
@@ -246,6 +256,17 @@ class CSRkTiles:
         if self.val_scale is not None:
             per_tile += (self.slots // INT8_GROUP) * 4
         return self.num_tiles * per_tile + self.remainder_nnz * 12
+
+    def chunks_visited(self, chunk: int) -> np.ndarray:
+        """One-hot chunks of width ``chunk`` each tile's gather visits
+        (host-side, ``[num_tiles]``): the distinct chunks holding a block
+        of its ``col_blocks`` row."""
+        cb = np.asarray(self.col_blocks)
+        count, q = cb[:, 0], cb[:, 1:] // (chunk // 128)
+        listed = np.arange(q.shape[1]) < count[:, None]
+        first = np.ones_like(listed)
+        first[:, 1:] = q[:, 1:] != q[:, :-1]
+        return (listed & first).sum(axis=1)
 
     def col_reach(self):
         """Per-tile real column reach ``(lo, hi)`` (host-side, numpy).
@@ -295,6 +316,27 @@ def _pack_values(tvals: np.ndarray, value_dtype: str):
     raise ValueError(
         f"unknown value_dtype {value_dtype!r} (expected f32|bf16|int8)"
     )
+
+
+def col_block_table(local_col: np.ndarray, tile_nnz: np.ndarray,
+                    window: int) -> np.ndarray:
+    """The ``col_blocks`` table of a tile view (host-side, numpy).
+
+    Marks, per tile, the 128-column blocks of its ``2·window`` x-window that
+    hold the column of one of its first ``tile_nnz`` slots, and lists them
+    (see :class:`CSRkTiles`).
+    """
+    lc = np.asarray(local_col)
+    T, S = lc.shape
+    nb = 2 * window // 128
+    t, s = np.nonzero(np.arange(S) < np.asarray(tile_nnz)[:, None])
+    marked = np.zeros((T, nb), bool)
+    marked[t, lc[t, s] // 128] = True
+    count = marked.sum(axis=1)
+    K = max(int(count.max(initial=0)), 1)
+    listed = np.argsort(~marked, axis=1, kind="stable")[:, :K]
+    listed = np.where(np.arange(K) < count[:, None], listed, 0)
+    return np.concatenate([count[:, None], listed], axis=1).astype(np.int32)
 
 
 def tiles_from_csrk(
@@ -400,6 +442,7 @@ def tiles_from_csrk(
         val_scale=dscale,
         tile_nnz=jnp.asarray(tnnz, _INT),
         value_dtype=value_dtype,
+        col_blocks=jnp.asarray(col_block_table(tlc, tnnz, window)),
     )
 
 
@@ -499,6 +542,7 @@ def bucket_tiles(tiles: CSRkTiles) -> CSRkTileBuckets:
     lr = np.asarray(tiles.local_row)
     wb = np.asarray(tiles.win_block)
     sc = None if tiles.val_scale is None else np.asarray(tiles.val_scale)
+    cb = np.asarray(tiles.col_blocks)
     if tiles.tile_nnz is not None:
         nnz_t = np.asarray(tiles.tile_nnz)
     else:  # hand-built views: padding is 0-valued, real zeros are not packed
@@ -525,6 +569,7 @@ def bucket_tiles(tiles: CSRkTiles) -> CSRkTileBuckets:
             val_scale=scale_b,
             tile_nnz=jnp.asarray(nnz_t[sel], _INT),
             value_dtype=tiles.value_dtype,
+            col_blocks=jnp.asarray(cb[sel]),
         ))
         ids.append(jnp.asarray(sel, _INT))
     return CSRkTileBuckets(

@@ -281,6 +281,16 @@ for strat in ("replicated", "allgather"):
     o = prepare(A, mesh=mesh, x_strategy=strat)
     assert bool(jnp.all(o(x) == single(x))), strat
     assert bool(jnp.all(o(X) == single(X))), strat
+    assert "cblk" in o.shard_arrays, strat
+# every tile subset carries its rows of the chunk table, and the single
+# launch equals a sweep of every chunk of every window
+assert {"i_cblk", "b_cblk"} <= set(ov.shard_arrays) and "cblk" in bl.shard_arrays
+import dataclasses
+T, nb = single.tiles.num_tiles, 2 * single.tiles.window // 128
+every = np.concatenate([np.full((T, 1), nb), np.tile(np.arange(nb), (T, 1))], 1)
+full = dataclasses.replace(single, tiles=dataclasses.replace(
+    single.tiles, col_blocks=jnp.asarray(every, jnp.int32)))
+assert bool(jnp.all(full(x) == single(x))) and bool(jnp.all(full(X) == single(X)))
 
 # sellcs: banded but row-irregular, so the SELL-C-sigma backend gets a
 # staged plan of its own (C-row chunks instead of SSR tiles)

@@ -148,3 +148,88 @@ def test_bucketed_survives_jit_closure(rng):
     f = jax.jit(lambda b, v: ref.spmv_csrk_buckets(b, v))
     np.testing.assert_allclose(np.asarray(f(buckets, jnp.asarray(x))),
                                dense @ x, rtol=2e-3, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the chunk table rides with the buckets and leaves every bit in place
+# ---------------------------------------------------------------------------
+
+def _banded_random(rng, n=2048, half_band=300, per_row=6):
+    """Random banded matrix: each row holds its diagonal and ``per_row``
+    columns drawn from ``[i − half_band, i + half_band]``, not symmetric."""
+    rows, cols = [], []
+    for i in range(n):
+        lo, hi = max(i - half_band, 0), min(i + half_band + 1, n)
+        c = np.unique(np.append(rng.integers(lo, hi, size=per_row), i))
+        rows += [i] * len(c)
+        cols += list(c)
+    dense = np.zeros((n, n), np.float32)
+    dense[rows, cols] = rng.standard_normal(len(rows))
+    return CSRMatrix.fromdense(dense), dense
+
+
+def _sweep_all(buckets):
+    """The buckets with every 128-block of every window listed: every tile
+    sweeps every chunk, as the kernel did before it read a table."""
+    nb = 2 * buckets.window // 128
+    out = []
+    for b in buckets.buckets:
+        T = b.num_tiles
+        cb = np.concatenate([np.full((T, 1), nb), np.tile(np.arange(nb), (T, 1))], 1)
+        out.append(dataclasses.replace(b, col_blocks=jnp.asarray(cb, jnp.int32)))
+    return dataclasses.replace(buckets, buckets=tuple(out))
+
+
+@pytest.fixture(scope="module")
+def grid300():
+    from repro.configs.spmv_suite import grid_laplacian_2d
+    from repro.obs import MetricsRegistry, using_registry
+
+    reg = MetricsRegistry()
+    with using_registry(reg):
+        op = prepare(grid_laplacian_2d(300, 300), device="tpu_v5e", format="csrk")
+    return op, reg
+
+
+@pytest.mark.parametrize("case,chunk", [("grid300", 256), ("grid300", 512),
+                                        ("banded", 128), ("banded", 512)])
+def test_chunk_table_bucketed_bit_for_bit(rng, request, case, chunk):
+    """The kernel with the built table equals the full sweep bit for bit:
+    the ecology1 structure at 300 × 300 after Band-k, and a random band."""
+    if case == "grid300":
+        op = request.getfixturevalue("grid300")[0]
+        buckets, n = op.tile_buckets, op.tiles.shape[1]
+    else:
+        A, _ = _banded_random(rng)
+        buckets, n = bucket_tiles(tiles_from_csrk(build_csrk(A, srs=8, ssrs=4, k=3))), A.n
+    visited = sum(int(b.chunks_visited(chunk).sum()) for b in buckets.buckets)
+    assert visited < buckets.num_tiles * 2 * buckets.window // chunk
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    run = lambda bk, v: np.asarray(ops.spmv_csrk_bucketed(
+        bk, v, gather_chunk=chunk, interpret=True)).view(np.int32)
+    np.testing.assert_array_equal(run(buckets, x), run(_sweep_all(buckets), x))
+    if chunk == 512:
+        X = jnp.asarray(rng.standard_normal((n, 2)), jnp.float32)
+        np.testing.assert_array_equal(run(buckets, X), run(_sweep_all(buckets), X))
+
+
+def test_chunk_table_sliced_with_buckets_and_gauged(grid300):
+    """Each bucket keeps its tiles' table rows, and ``prepare`` gauges the
+    share of a full sweep the kernel visits."""
+    from repro.kernels.gather import pick_chunk
+
+    op, reg = grid300
+    tiles, buckets = op.tiles, op.tile_buckets
+    cb = np.asarray(tiles.col_blocks)
+    for b, ids in zip(buckets.buckets, buckets.tile_ids):
+        np.testing.assert_array_equal(np.asarray(b.col_blocks), cb[np.asarray(ids)])
+    # every real slot's block is listed, and only those
+    lc, nnz_t = np.asarray(tiles.local_col), np.asarray(tiles.tile_nnz)
+    for t in range(0, tiles.num_tiles, 97):
+        want = np.unique(lc[t, :nnz_t[t]] // 128)
+        np.testing.assert_array_equal(cb[t, 1:1 + cb[t, 0]], want)
+    chunk = pick_chunk(tiles.window, op.params.gather_chunk)
+    share = reg.get("prepare", "csrk.onehot_share")
+    sweep = tiles.num_tiles * 2 * tiles.window // chunk
+    assert share == pytest.approx(100 * tiles.chunks_visited(chunk).sum() / sweep)
+    assert 0 < share < 100

@@ -29,6 +29,14 @@ from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
 #: case a whole grid step has to be padded for.
 ECOLOGY1 = dict(buckets=((1, 384), (8928, 640)), R=112, W=6784, n=1_000_000)
 
+#: The same matrix as Band-k orders it on the chip's host: a narrower
+#: window, so 256-column one-hot chunks (6784 = 53·128 allows only 128).
+ECOLOGY1_CHIP = dict(ECOLOGY1, W=4864)
+
+#: At most 12 of a tile's 128-column window blocks hold one of its columns:
+#: the ``col_blocks`` table is one count column and 12 block columns.
+TABLE_WIDTH = 13
+
 VALUE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
@@ -80,6 +88,7 @@ def test_csrk_compiles_at_ecology1(one_chip, vd, B):
             _spec((T, S), jnp.int32, one_chip),
             _spec((T, S), jnp.int32, one_chip),
             _spec((T,), jnp.int32, one_chip),
+            _spec((T, TABLE_WIDTH), jnp.int32, one_chip),
             _x(L, B, one_chip),
             scale,
             rows_per_tile=R, window=W, gather_chunk=512,
@@ -129,3 +138,26 @@ def test_diahybrid_compiles_at_its_limit(one_chip, B):
         _x(L, B, one_chip),
         offsets=offsets, lead=lead, row_tile=row_tile,
     )
+
+
+@pytest.mark.parametrize("B", [1, 8])
+@pytest.mark.parametrize("vd", ["f32", "bf16", "int8"])
+def test_csrk_chunk_table_compiles_at_ecology1(one_chip, vd, B):
+    """The chip host's geometry: 256-column chunks, two 128-column blocks
+    each, so a listed block can fall in the chunk visited last."""
+    R, W = ECOLOGY1_CHIP["R"], ECOLOGY1_CHIP["W"]
+    L = (-(-ECOLOGY1_CHIP["n"] // W) + 1) * W
+    for T, S in ECOLOGY1_CHIP["buckets"]:
+        scale = (_spec((T, S // 128), jnp.float32, one_chip)
+                 if vd == "int8" else None)
+        _assert_mosaic(
+            spmv_csrk_tiles_pallas,
+            _spec((T, S), VALUE_DTYPES[vd], one_chip),
+            _spec((T, S), jnp.int32, one_chip),
+            _spec((T, S), jnp.int32, one_chip),
+            _spec((T,), jnp.int32, one_chip),
+            _spec((T, TABLE_WIDTH), jnp.int32, one_chip),
+            _x(L, B, one_chip),
+            scale,
+            rows_per_tile=R, window=W, gather_chunk=512,
+        )
