@@ -69,8 +69,13 @@ def grid_laplacian_2d(nx: int, ny: int, stencil: int = 5) -> CSRMatrix:
     return _sym_coo(n, r, c, -np.ones(len(r)))
 
 
-def grid_laplacian_3d(nx: int, ny: int, nz: int) -> CSRMatrix:
-    """7-point 3D Laplacian (2D/3D problem family: brack2/wave)."""
+def grid_laplacian_3d(nx: int, ny: int, nz: int, stencil: int = 7) -> CSRMatrix:
+    """7-point 3D Laplacian (2D/3D problem family: brack2/wave), or HPCG's
+    27-point operator (``stencil=27``, :func:`hpcg_27pt`)."""
+    if stencil == 27:
+        return hpcg_27pt(nx, ny, nz)
+    if stencil != 7:
+        raise ValueError(f"no {stencil}-point 3D stencil (expected 7 or 27)")
     n = nx * ny * nz
     idx = np.arange(n).reshape(nx, ny, nz)
     rows, cols = [], []
@@ -80,6 +85,26 @@ def grid_laplacian_3d(nx: int, ny: int, nz: int) -> CSRMatrix:
     r = np.concatenate(rows)
     c = np.concatenate(cols)
     return _sym_coo(n, r, c, -np.ones(len(r)))
+
+
+def hpcg_27pt(nx: int, ny: int, nz: int) -> CSRMatrix:
+    """HPCG's operator (``GenerateProblem_ref.cpp``): on an nx × ny × nz grid,
+    every point couples to each of its up to 26 neighbours with −1 and to
+    itself with 26, so border rows hold 18, 12 or 8 nonzeros and the matrix
+    (3n − 2)³ on an n³ grid.  Point (i, j, k) is row ``(i·ny + j)·nz + k``;
+    each row's columns ascend."""
+    idx = np.arange(nx * ny * nz, dtype=np.int32).reshape(nx, ny, nz)
+    pad = np.pad(idx, 1, constant_values=-1)
+    # the 27 offsets in lexicographic order are the columns in ascending order
+    cols = np.stack([pad[1 + di:1 + di + nx, 1 + dj:1 + dj + ny, 1 + dk:1 + dk + nz]
+                     for di in (-1, 0, 1) for dj in (-1, 0, 1) for dk in (-1, 0, 1)],
+                    axis=-1).reshape(-1, 27)
+    vals = np.where(np.arange(27) == 13, 26.0, -1.0).astype(np.float32)
+    keep = cols >= 0
+    row_ptr = np.concatenate([[0], np.cumsum(keep.sum(axis=1))]).astype(np.int32)
+    n = idx.size
+    return CSRMatrix(jnp.asarray(row_ptr), jnp.asarray(cols[keep]),
+                     jnp.asarray(np.broadcast_to(vals, cols.shape)[keep]), (n, n))
 
 
 def road_graph(n: int, seed: int = 0) -> CSRMatrix:

@@ -69,6 +69,7 @@ def cg(
     *,
     tol: float = 1e-6,
     maxiter: int = 500,
+    precond: MatVec | None = None,
 ) -> CGResult:
     """Conjugate gradients for SPD A (paper Sec. 1: the SpMV consumer).
 
@@ -79,36 +80,53 @@ def cg(
       x0: optional initial guess, shape [n] (defaults to zeros).
       tol: relative residual tolerance (on ‖r‖ / ‖b‖).
       maxiter: iteration cap.
+      precond: optional z = M⁻¹ r for an SPD M (e.g.
+        :meth:`repro.core.multigrid.Hierarchy.vcycle`); given, the loop is
+        preconditioned CG, one ``precond`` per iteration and one before it.
+        None runs plain CG, the same operations as before ``precond``
+        existed, so its iterates are unchanged bit for bit.
 
     Returns:
       :class:`CGResult` with the solution ``x`` [n], iteration count and the
-      final residual norm.
+      final residual norm (of r, not of M⁻¹ r).
     """
     t_start = time.perf_counter()
     x0 = jnp.zeros_like(b) if x0 is None else x0
     r0 = b - matvec(x0)
-    p0 = r0
     rs0 = jnp.vdot(r0, r0)
+    # PCG carries ⟨r, z⟩ after k and hist; plain CG has z = r and ⟨r, z⟩ = rs
+    if precond is None:
+        p0, extra = r0, ()
+    else:
+        p0 = precond(r0)
+        extra = (jnp.vdot(r0, p0),)
     tol2 = jnp.asarray(tol, b.dtype) ** 2 * jnp.maximum(jnp.vdot(b, b), 1e-30)
     hist0 = jnp.zeros((maxiter,), jnp.float32)
 
     def cond(state):
-        _, _, _, rs, k, _ = state
+        _, _, _, rs, k, _, *_ = state
         return jnp.logical_and(rs > tol2, k < maxiter)
 
     def body(state):
-        x, r, p, rs, k, hist = state
+        x, r, p, rs, k, hist, *rz = state
+        rz = rz[0] if rz else rs
         Ap = matvec(p)
-        alpha = rs / jnp.maximum(jnp.vdot(p, Ap), 1e-30)
+        alpha = rz / jnp.maximum(jnp.vdot(p, Ap), 1e-30)
         x = x + alpha * p
         r = r - alpha * Ap
         rs_new = jnp.vdot(r, r)
-        p = r + (rs_new / jnp.maximum(rs, 1e-30)) * p
+        if precond is None:
+            z, rz_new, extra = r, rs_new, ()
+        else:
+            z = precond(r)
+            rz_new = jnp.vdot(r, z)
+            extra = (rz_new,)
+        p = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
         hist = hist.at[k].set(jnp.sqrt(rs_new).astype(jnp.float32))
-        return (x, r, p, rs_new, k + 1, hist)
+        return (x, r, p, rs_new, k + 1, hist, *extra)
 
-    x, r, _, rs, k, hist = jax.lax.while_loop(
-        cond, body, (x0, r0, p0, rs0, 0, hist0)
+    x, r, _, rs, k, hist, *_ = jax.lax.while_loop(
+        cond, body, (x0, r0, p0, rs0, 0, hist0, *extra)
     )
     _record_solve("cg", k, hist, t_start)
     return CGResult(x=x, iters=k, residual=jnp.sqrt(rs))
@@ -241,10 +259,13 @@ def block_power_iteration(
 
 
 def jacobi_smoother(
-    matvec: MatVec, diag: jax.Array, b: jax.Array, *, iters: int = 10, omega: float = 0.67
+    matvec: MatVec, diag: jax.Array, b: jax.Array, x0: jax.Array | None = None, *,
+    iters: int = 10, omega: float = 0.67
 ) -> jax.Array:
-    """Weighted-Jacobi relaxation (SpMV per sweep) — multigrid building block."""
-    x = jnp.zeros_like(b)
+    """Weighted-Jacobi relaxation of A x = b from ``x0`` (zeros when None):
+    ``iters`` sweeps x ← x + ω D⁻¹ (b − A x), one SpMV each — the smoother of
+    :mod:`repro.core.multigrid`."""
+    x = jnp.zeros_like(b) if x0 is None else x0
 
     def body(_, x):
         return x + omega * (b - matvec(x)) / diag

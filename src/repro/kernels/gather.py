@@ -323,10 +323,15 @@ def tile_rows(v, lc, lr, x_refs, bases, *, rows, chunk, parts, gather_mode,
     return reduce_rows(v * g, lr, rows, dot_dtype)
 
 
+#: The most scoped VMEM a kernel asks for: what one v5e core can grant of
+#: its 128 MiB, less room for the compiler's own.
+VMEM_CAP = 100 << 20
+
+
 def vmem_limit(nbytes: int) -> int:
     """Scoped-VMEM request for a kernel whose buffers total ``nbytes``.
 
     Twice the estimate plus headroom for Mosaic's temporaries, clamped to
-    what one v5e core can grant (128 MiB physical).
+    :data:`VMEM_CAP`.
     """
-    return int(min(max(2 * nbytes + (8 << 20), 16 << 20), 100 << 20))
+    return int(min(max(2 * nbytes + (8 << 20), 16 << 20), VMEM_CAP))

@@ -3,7 +3,9 @@
 Mapping (DESIGN §2):
   * super-super-rows     → tiles; :data:`TILES_PER_STEP` tiles per grid step
                            (one ``[8, S]`` HBM→VMEM move per tile stream, the
-                           sublane-aligned block Mosaic requires)
+                           sublane-aligned block Mosaic requires), or, where
+                           their x windows would not fit in VMEM, the same
+                           blocks over :func:`x_tiles_per_step` sub-steps
   * intra-tile nnz slots → lanes
   * x[col_idx] gather    → contiguous banded x-window per tile (two adjacent
                            blocks of ``window`` columns, placed by a
@@ -32,8 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.gather import (
-    dequant, gather_dtype, pad_tiles, pick_chunk, resolve_interpret, round_up,
-    split_f32, tile_rows, vmem_limit,
+    VMEM_CAP, dequant, gather_dtype, pad_tiles, pick_chunk, resolve_interpret,
+    round_up, split_f32, tile_rows, vmem_limit,
 )
 
 GatherMode = Literal["onehot", "take"]
@@ -43,14 +45,41 @@ GatherMode = Literal["onehot", "take"]
 TILES_PER_STEP = 8
 
 
+def _vmem_bytes(S: int, window: int, xrows: int, chunk: int, x_tiles: int) -> int:
+    """The kernel's VMEM estimate: double-buffered tile blocks and x blocks
+    (``x_tiles`` tiles' two windows of ``xrows`` rows), plus one one-hot slab
+    and its product."""
+    TB = TILES_PER_STEP
+    return (2 * (4 * TB * S * 4 + 2 * x_tiles * max(xrows, 16) * window * 4)
+            + 3 * chunk * S * 4)
+
+
+def x_tiles_per_step(S: int, window: int, xrows: int, chunk: int) -> int:
+    """Tiles whose x windows one grid step holds: a divisor of
+    :data:`TILES_PER_STEP`.
+
+    All 8 wherever :func:`vmem_limit` can grant the estimate its full room
+    (twice it, plus headroom for Mosaic's temporaries, under its cap), so
+    every launch that fitted at 8 a step keeps its grid; else the most that
+    do, and at least 1.  A wide window (a 3-D stencil's after Band-k) is
+    what takes fewer: then each 8-tile block of the tile streams is visited
+    over ``8 / x_tiles`` sub-steps, each with its own tiles' windows.
+    """
+    for x_tiles in (TILES_PER_STEP, 4, 2):
+        if 2 * _vmem_bytes(S, window, xrows, chunk, x_tiles) + (8 << 20) <= VMEM_CAP:
+            return x_tiles
+    return 1
+
+
 def _kernel(
     win_ref,       # scalar prefetch: [T_pad] int32 window block per tile
     blocks_ref,    # [TB, 1+K] int32 in SMEM: each tile's col_blocks row
     vals_ref,      # [TB, S]
     lc_ref,        # [TB, S]
     lr_ref,        # [TB, S]
-    *rest,         # ([scale_ref [TB, G],] 2·TB x refs [P·B, W], y_ref [TB·B, Rp])
+    *rest,         # ([scale_ref [TB, G],] 2·X x refs [P·B, W], y_ref [TB·B, Rp])
     tiles: int,
+    x_tiles: int,
     batch: int,
     rows: int,
     window: int,
@@ -66,14 +95,21 @@ def _kernel(
     v = dequant(vals_ref[...], None if scale_ref is None else scale_ref[...])
     lc, lr = lc_ref[...], lr_ref[...]
     for j in range(tiles):
-        y = tile_rows(
-            v[j:j + 1], lc[j:j + 1], lr[j:j + 1],
-            x_refs[2 * j:2 * j + 2], (0, window),
-            rows=rows, chunk=chunk, parts=parts, gather_mode=gather_mode,
-            dot_dtype=dot_dtype,
-            blocks=(blocks_ref, j),
-        )
-        y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
+        def one_tile(j=j):
+            i = j % x_tiles            # this sub-step's x refs hold tile j's window
+            y = tile_rows(
+                v[j:j + 1], lc[j:j + 1], lr[j:j + 1],
+                x_refs[2 * i:2 * i + 2], (0, window),
+                rows=rows, chunk=chunk, parts=parts, gather_mode=gather_mode,
+                dot_dtype=dot_dtype,
+                blocks=(blocks_ref, j),
+            )
+            y_ref[j * batch:(j + 1) * batch, :] = y.astype(y_ref.dtype)
+
+        if x_tiles == tiles:
+            one_tile()
+        else:
+            pl.when(pl.program_id(1) == j // x_tiles)(one_tile)
 
 
 @functools.partial(
@@ -134,38 +170,48 @@ def spmv_csrk_tiles_pallas(
     )
     Rp = round_up(rows_per_tile, 8)
     chunk = pick_chunk(window, gather_chunk)
+    X = x_tiles_per_step(S, window, parts * B, chunk)
+    sub = TB // X
 
-    tile_spec = pl.BlockSpec((TB, S), lambda t, w: (t, 0))
+    # one grid step per TB-tile block; with sub > 1 the block stays resident
+    # over its sub-steps, and sub-step g holds tiles g·X … g·X + X − 1's x
+    if sub == 1:
+        grid, block = (steps,), (lambda t, w: (t, 0))
+    else:
+        grid, block = (steps, sub), (lambda t, g, w: (t, 0))
+    tile_spec = pl.BlockSpec((TB, S), block)
     # every row of the last step's table block is real or zero: a zero count
     # visits nothing, where a partial block's unwritten rows could hold any
-    in_specs = [pl.BlockSpec((TB, col_blocks.shape[1]), lambda t, w: (t, 0),
+    in_specs = [pl.BlockSpec((TB, col_blocks.shape[1]), block,
                              memory_space=pltpu.SMEM)] + [tile_spec] * 3
     operands = [jnp.pad(col_blocks, ((0, steps * TB - T), (0, 0))),
                 vals, local_col, local_row]
     if val_scale is not None:
-        in_specs.append(pl.BlockSpec((TB, val_scale.shape[1]), lambda t, w: (t, 0)))
+        in_specs.append(pl.BlockSpec((TB, val_scale.shape[1]), block))
         operands.append(val_scale)
-    for j in range(TB):
-        # tile t·TB+j reads window blocks w and w+1 of x
-        in_specs += [
-            pl.BlockSpec((parts * B, window), lambda t, w, j=j: (0, w[t * TB + j])),
-            pl.BlockSpec((parts * B, window), lambda t, w, j=j: (0, w[t * TB + j] + 1)),
-        ]
+    for j in range(X):
+        # tile t·TB+j (+ g·X) reads window blocks w and w+1 of x
+        if sub == 1:
+            lo = lambda t, w, j=j: (0, w[t * TB + j])
+            hi = lambda t, w, j=j: (0, w[t * TB + j] + 1)
+        else:
+            lo = lambda t, g, w, j=j: (0, w[t * TB + g * X + j])
+            hi = lambda t, g, w, j=j: (0, w[t * TB + g * X + j] + 1)
+        in_specs += [pl.BlockSpec((parts * B, window), lo),
+                     pl.BlockSpec((parts * B, window), hi)]
         operands += [xg, xg]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(steps,),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((TB * B, Rp), lambda t, w: (t, 0)),
+        out_specs=pl.BlockSpec((TB * B, Rp), block),
     )
     kernel = functools.partial(
-        _kernel, tiles=TB, batch=B, rows=Rp, window=window, chunk=chunk,
+        _kernel, tiles=TB, x_tiles=X, batch=B, rows=Rp, window=window, chunk=chunk,
         parts=parts, gather_mode=gather_mode, has_scale=val_scale is not None,
         dot_dtype=gather_dtype(interpret),
     )
-    # double-buffered tile and x blocks, plus one one-hot slab and its product
-    vmem = (2 * (4 * TB * S * 4 + 2 * TB * max(parts * B, 16) * window * 4)
-            + 3 * chunk * S * 4)
+    vmem = _vmem_bytes(S, window, parts * B, chunk, X)
     y = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

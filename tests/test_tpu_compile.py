@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.gather import WHOLE_X_MAX_COLS
-from repro.kernels.spmv_csrk import spmv_csrk_tiles_pallas
+from repro.kernels.gather import pick_chunk
+from repro.kernels.spmv_csrk import spmv_csrk_tiles_pallas, x_tiles_per_step
 from repro.kernels.spmv_diahybrid import spmv_dia_pallas
 from repro.kernels.spmv_segsum import spmv_segsum_pallas
 from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
@@ -36,6 +37,12 @@ ECOLOGY1_CHIP = dict(ECOLOGY1, W=4864)
 #: At most 12 of a tile's 128-column window blocks hold one of its columns:
 #: the ``col_blocks`` table is one count column and 12 block columns.
 TABLE_WIDTH = 13
+
+#: HPCG's 104³ grid (1,124,864 rows, 27-point) after Band-k on a build
+#: host: its largest slot bucket at R = 56, S = 1536 and a window of 91,264
+#: columns (83,200 on the chip's host), whose x blocks at 8 tiles a step
+#: would overrun VMEM; 75 table blocks, as the chip's host has them.
+HPCG104 = dict(T=17_911, S=1536, R=56, W=91_264, n=1_124_864, table=76)
 
 VALUE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
@@ -161,3 +168,24 @@ def test_csrk_chunk_table_compiles_at_ecology1(one_chip, vd, B):
             scale,
             rows_per_tile=R, window=W, gather_chunk=512,
         )
+
+
+def test_csrk_compiles_at_hpcg104_level0(one_chip):
+    """A window this wide takes one tile's x blocks a grid step, over eight
+    sub-steps of each block of the tile streams.  The described chip does not
+    check VMEM (8 a step compiles here too), so the rule is pinned as well."""
+    g = HPCG104
+    T, S = g["T"], g["S"]
+    assert x_tiles_per_step(S, g["W"], 3, pick_chunk(g["W"], 512)) == 1
+    L = (-(-g["n"] // g["W"]) + 1) * g["W"]
+    _assert_mosaic(
+        spmv_csrk_tiles_pallas,
+        _spec((T, S), jnp.float32, one_chip),
+        _spec((T, S), jnp.int32, one_chip),
+        _spec((T, S), jnp.int32, one_chip),
+        _spec((T,), jnp.int32, one_chip),
+        _spec((T, g["table"]), jnp.int32, one_chip),
+        _x(L, 1, one_chip),
+        None,
+        rows_per_tile=g["R"], window=g["W"], gather_chunk=512,
+    )
