@@ -614,9 +614,12 @@ class ShardedPreparedSpMV:
         return self(X)
 
     def apply_original(self, x_old: jax.Array) -> jax.Array:
-        """SpMV / SpMM for vectors indexed in the matrix's original ordering."""
-        y_new = self(x_old[self.base._perm_dev])
-        return y_new[self.base._inv_perm_dev]
+        """SpMV / SpMM for vectors indexed in the matrix's original ordering
+        (``self(x_old)`` where the base operator's ``perm`` is the identity)."""
+        if self.base._identity_perm:
+            return self(x_old)
+        perm, inv_perm = self.base._perm_arrays
+        return self(x_old[perm])[inv_perm]
 
 
 def _build_plan_call(op: ShardedPreparedSpMV):

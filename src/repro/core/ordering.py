@@ -168,6 +168,101 @@ def rcm(csr: CSRMatrix) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# whether to reorder at all
+# ---------------------------------------------------------------------------
+
+
+def _neighbour_slots(ptr: np.ndarray, idx: np.ndarray, nodes: np.ndarray) -> np.ndarray:
+    """``idx[ptr[v]:ptr[v+1]]`` for every v in ``nodes``, concatenated."""
+    lo = ptr[nodes]
+    count = ptr[nodes + 1] - lo
+    first = np.repeat(lo - np.cumsum(count) + count, count)   # row start − rank base
+    return idx[first + np.arange(count.sum())]
+
+
+def level_sets(adjacency, start: int, levels: np.ndarray) -> List[np.ndarray]:
+    """Breadth-first level sets ``[L_0, L_1, ...]`` from ``start``, each sorted.
+
+    ``adjacency`` is a sequence of CSR ``(ptr, idx)`` pairs whose lists
+    together hold each node's neighbours (duplicates and self loops do no
+    harm).  One vectorised frontier step a level, so the work is O(edges of
+    the component) in numpy.  ``levels`` must read -1 on the component; it
+    is left holding each node's level, as ``_bfs_levels`` returns it.
+    """
+    levels[start] = 0
+    sets = [np.asarray([start], np.int64)]
+    while True:
+        nbrs = np.concatenate([_neighbour_slots(p, i, sets[-1]) for p, i in adjacency])
+        nxt = np.unique(nbrs[levels[nbrs] < 0])
+        if not nxt.size:
+            return sets
+        levels[nxt] = len(sets)
+        sets.append(nxt)
+
+
+def level_bandwidth(adjacency, degree: np.ndarray) -> int:
+    """The bandwidth a Cuthill–McKee-family order can be expected to reach.
+
+    For each connected component, the level structure of a breadth-first
+    search from a pseudo-peripheral node (the George–Liu search of
+    ``_pseudo_peripheral``, with its tie-breaks on ``degree``) bounds CM's
+    bandwidth by ``max_k(|L_k| + |L_{k+1}|) − 1``; the result is the largest
+    bound over the components.  Nodes of degree 0 bound nothing.
+    """
+    levels = np.full(len(degree), -1, np.int64)
+    reached = np.zeros(len(degree), bool)
+    todo = np.flatnonzero(degree > 0)
+    todo = todo[np.argsort(degree[todo], kind="stable")]     # least degree first
+    left, i, best = len(todo), 0, 0
+    while left:
+        while reached[todo[i]]:
+            i += 1
+        v, last_ecc = int(todo[i]), -1
+        for _ in range(9):      # _pseudo_peripheral's 8 searches, then its node's
+            sets = level_sets(adjacency, v, levels)
+            levels[np.concatenate(sets)] = -1
+            ecc = len(sets) - 1
+            if ecc <= last_ecc:
+                break
+            last_ecc = ecc
+            v = int(sets[-1][np.argmin(degree[sets[-1]])])
+        sizes = np.asarray([len(s) for s in sets])
+        best = max(best, int((sizes[:-1] + sizes[1:]).max()) - 1)
+        component = np.concatenate(sets)
+        reached[component] = True
+        left -= len(component)
+    return best
+
+
+def keeps_input_order(csr: CSRMatrix) -> Tuple[bool, int, int]:
+    """Whether the matrix's own order already bounds the band (``reorder="auto"``).
+
+    Returns ``(keep, band_input, band_levels)``: the input order's bandwidth
+    (:func:`bandwidth`), the bound :func:`level_bandwidth` gives for a
+    Cuthill–McKee-family order on the symmetrised pattern, and whether the
+    first is at most the second, in which case such a reorder is not expected
+    to narrow the band.  O(nnz) numpy: each node's neighbours are its row of
+    A together with its column (the transpose, from one stable sort of the
+    column indices), duplicates and all.
+    """
+    m, n = csr.shape
+    size = max(m, n)
+    rp = np.asarray(csr.row_ptr).astype(np.int64)
+    ci = np.asarray(csr.col_idx)
+    rows = np.repeat(np.arange(m, dtype=ci.dtype), np.diff(rp))
+    band_input = int(np.abs(rows.astype(np.int64) - ci).max(initial=0))
+    by_col = np.argsort(ci, kind="stable")
+    col_ptr = np.zeros(size + 1, np.int64)
+    np.cumsum(np.bincount(ci, minlength=size), out=col_ptr[1:])
+    row_ptr = np.concatenate([rp, np.full(size - m, rp[-1])])
+    off = rows != ci
+    degree = (np.bincount(rows[off], minlength=size)
+              + np.bincount(ci[off], minlength=size))
+    band_levels = level_bandwidth(((row_ptr, ci), (col_ptr, rows[by_col])), degree)
+    return band_input <= band_levels, band_input, band_levels
+
+
+# ---------------------------------------------------------------------------
 # coarsening (heavy-edge matching)
 # ---------------------------------------------------------------------------
 

@@ -4,8 +4,9 @@
 :class:`PreparedSpMV` whose ``__call__`` is a jit-compatible SpMV.
 
 For the paper's CSR-k path (regular matrices):
-  Band-k reorder → constant-time tune (SSRS/SRS from rdensity) → CSR-k build
-  → (TPU path) padded tile view.
+  Band-k reorder (unless the input order already bounds the band) →
+  constant-time tune (SSRS/SRS from rdensity) → CSR-k build → (TPU path)
+  padded tile view.
 The canonical CSR-k arrays stay CSR-compatible throughout (the heterogeneity
 property); the device decides only the *interpretation*.
 
@@ -73,7 +74,8 @@ class PreparedSpMV:
         y_old[perm] == P A P^T (x_old[perm])  ⇒  use ``apply_original``.
     The SELL-C-σ, segsum and diahybrid paths never permute A (SELL's σ-sort
     is internal to its container; the other two consume CSR order directly),
-    so there ``perm`` is the identity.
+    so there ``perm`` is the identity, as it is on the CSR-k path wherever
+    ``reorder`` kept the input order.
     """
 
     csrk: Optional[CSRkMatrix]
@@ -98,10 +100,13 @@ class PreparedSpMV:
         # Device-resident permutation arrays, built once at prepare() time so
         # apply_original never re-uploads host numpy per call.  argsort gives
         # the inverse permutation (inv[perm[i]] == i), turning the output
-        # scatter into a cheaper gather with bit-identical placement.
+        # scatter into a cheaper gather with bit-identical placement.  An
+        # identity ``perm`` keeps none: apply_original is then the call itself.
         perm_host = np.asarray(self.perm)
-        object.__setattr__(self, "_perm_dev", jnp.asarray(perm_host))
-        object.__setattr__(self, "_inv_perm_dev", jnp.asarray(np.argsort(perm_host)))
+        identity = bool(np.array_equal(perm_host, np.arange(perm_host.size)))
+        object.__setattr__(self, "_identity_perm", identity)
+        object.__setattr__(self, "_perm_arrays", () if identity else (
+            jnp.asarray(perm_host), jnp.asarray(np.argsort(perm_host))))
 
     @property
     def csr(self) -> CSRMatrix:
@@ -198,13 +203,18 @@ class PreparedSpMV:
           y = A x in the original index space, [m] resp. [m, B] — the
           permutation is applied on the way in and inverted on the way out
           using device-resident index arrays cached at ``prepare`` time.
+          Where ``perm`` is the identity this is ``self(x_old)``, with no
+          gathers and no permute spans.
         """
         with annotate("repro.apply_original"):
+            if self._identity_perm:
+                return self(x_old)
+            perm, inv_perm = self._perm_arrays
             with annotate("repro.permute_in"):
-                x_new = x_old[self._perm_dev]
+                x_new = x_old[perm]
             y_new = self(x_new)
             with annotate("repro.permute_out"):
-                return y_new[self._inv_perm_dev]
+                return y_new[inv_perm]
 
     # -- introspection used by benchmarks ------------------------------------
     def overhead_fraction(self) -> float:
@@ -259,8 +269,7 @@ class PreparedSpMV:
         """
         leaves = jax.tree_util.tree_leaves((
             self.csrk, self.tiles, self.tile_buckets, self.sell,
-            self.sell_tiles, self.segsum, self.dia,
-            self._perm_dev, self._inv_perm_dev,
+            self.sell_tiles, self.segsum, self.dia, self._perm_arrays,
         ))
         return sum(int(leaf.nbytes) for leaf in leaves
                    if hasattr(leaf, "nbytes"))
@@ -297,7 +306,7 @@ def _record_prepared(op: PreparedSpMV) -> PreparedSpMV:
                        op.tiles.col_blocks)
         else:
             uploads = (op.csrk.csr.vals, op.csrk.csr.col_idx)
-        for arr in uploads + (op._perm_dev, op._inv_perm_dev):
+        for arr in uploads + op._perm_arrays:
             jax.block_until_ready(arr)
     reg.counter("prepare", f"backend.{op.backend}")
     reg.gauge("prepare", "padding_overhead", op.padding_overhead(),
@@ -384,7 +393,7 @@ def prepare(
     device: str = "tpu_v5e",
     *,
     format: str = "auto",             # "auto" | "csrk" | "sellcs" | "segsum" | "diahybrid"
-    reorder: str = "bandk",           # "bandk" | "rcm" | "natural"
+    reorder: str = "auto",            # "auto" | "bandk" | "rcm" | "natural"
     params: tuner_mod.TuningParams | None = None,
     gather_mode: str = "onehot",
     gather_chunk: int | None = None,
@@ -412,15 +421,15 @@ def prepare(
       format: storage backend selection:
 
         * ``"auto"`` — compute one-pass :class:`~repro.sparse.MatrixStats`
-          (nnz/row mean + variance, rdensity, diag_fraction, row_skew,
-          post-Band-k bandwidth) and dispatch via the registry's O(1)
-          :func:`~repro.sparse.select_format`: matrices with nnz/row variance
-          ≤ 10 (the paper's Sec. 6 regularity bound) take the CSR-k path,
-          bit-for-bit identical to ``format="csrk"``; irregular matrices take
-          SELL-C-σ, unless they are power-law skewed (row_skew ≥ 8 →
-          ``segsum``) or near-fully diagonal (diag_fraction ≥ 0.9 →
-          ``diahybrid``).
-        * ``"csrk"`` — force the paper's path: Band-k reorder →
+          (nnz/row mean + variance, rdensity, diag_fraction, row_skew, the
+          bandwidth in the order the operator uses) and dispatch via the
+          registry's O(1) :func:`~repro.sparse.select_format`: matrices
+          with nnz/row variance ≤ 10 (the paper's Sec. 6 regularity bound)
+          take the CSR-k path, bit-for-bit identical to ``format="csrk"``;
+          irregular matrices take SELL-C-σ, unless they are power-law
+          skewed (row_skew ≥ 8 → ``segsum``) or near-fully diagonal
+          (diag_fraction ≥ 0.9 → ``diahybrid``).
+        * ``"csrk"`` — force the paper's path: reorder (``reorder``) →
           constant-time tune from rdensity → CSR-k build → padded tile view.
         * ``"sellcs"`` — force SELL-C-σ: σ-window sort → C-row chunks →
           per-chunk padded slices → uniform-width Pallas view.  No Band-k
@@ -433,8 +442,20 @@ def prepare(
           al.): diagonals with occupancy ≥ ``diag_occupancy`` become a DIA
           plane (shifted dense contraction in Pallas), the rest rides the
           CSR oracle path.  ``perm`` stays identity.
-      reorder: global reordering for the CSR-k path ("bandk" | "rcm" |
-        "natural").
+      reorder: global reordering for the CSR-k path:
+
+        * ``"auto"`` (default) — keep the input order where it already bounds
+          the band: when the input's bandwidth is at most
+          ``max_k(|L_k| + |L_{k+1}|) − 1`` over the breadth-first level sets
+          from a pseudo-peripheral node (the bandwidth a Cuthill–McKee-family
+          order can be expected to reach; the largest over the connected
+          components), ``perm`` is the identity and ``apply_original``
+          gathers nothing; otherwise Band-k, as ``"bandk"``.  The decision
+          costs O(nnz) and sets the gauges ``prepare/reorder.kept_input_order``,
+          ``reorder.band_input`` and ``reorder.band_levels``.
+        * ``"bandk"`` — the paper's Band-k (k = 3), whatever the input order.
+        * ``"rcm"`` — reverse Cuthill–McKee.
+        * ``"natural"`` — the input order.
       params: explicit :class:`~repro.core.tuner.TuningParams`; None runs the
         constant-time tuner.
       gather_mode: in-kernel x-gather ("onehot" MXU matmuls | "take").
@@ -621,6 +642,12 @@ def prepare(
         )
 
     with reg.timer("prepare", "phase.reorder"):
+        if reorder == "auto":
+            keep, band_input, band_levels = bandk_mod.keeps_input_order(A)
+            reg.gauge("prepare", "reorder.kept_input_order", float(keep), unit="flag")
+            reg.gauge("prepare", "reorder.band_input", band_input, unit="count")
+            reg.gauge("prepare", "reorder.band_levels", band_levels, unit="count")
+            reorder = "natural" if keep else "bandk"
         if reorder == "bandk":
             perm = bandk_mod.bandk(A, k=3)
         elif reorder == "rcm":
@@ -629,12 +656,14 @@ def prepare(
             perm = np.arange(A.m)
         else:
             raise ValueError(f"unknown reorder {reorder!r}")
-        with reg.timer("prepare", "phase.reorder.symperm"):
-            Ar = A.symmetric_permute(perm) if reorder != "natural" else A
-            if stats is not None and reorder != "natural":
-                # report the post-reordering bandwidth (row-length stats are
-                # permutation-invariant, so the routing decision is unaffected)
-                stats = compute_stats(Ar)
+        Ar = A
+        if reorder != "natural":
+            with reg.timer("prepare", "phase.reorder.symperm"):
+                Ar = A.symmetric_permute(perm)
+                if stats is not None:
+                    # report the post-reordering bandwidth (row-length stats
+                    # are permutation-invariant, so routing is unaffected)
+                    stats = compute_stats(Ar)
 
     with reg.timer("prepare", "phase.tune"):
         if params is None:

@@ -314,7 +314,7 @@ def _host_spans(log_dir, prefix):
 def test_apply_original_spans_nest_in_order_in_a_capture(rng, tmp_path):
     A = grid_laplacian_2d(16, 16)
     with using_registry(MetricsRegistry()):
-        op = prepare(A, device="tpu_v5e", format="csrk")
+        op = prepare(A, device="tpu_v5e", format="csrk", reorder="bandk")
         assert op.tile_buckets is not None
         x = jnp.asarray(rng.standard_normal(A.n), jnp.float32)
         op.apply_original(x).block_until_ready()
@@ -341,6 +341,24 @@ def test_apply_original_spans_nest_in_order_in_a_capture(rng, tmp_path):
     assert by["repro.spmv_csrk_bucketed"][1] <= by["repro.permute_out"][0]
 
 
+def test_identity_perm_apply_original_opens_no_permute_span(tmp_path):
+    A = grid_laplacian_2d(16, 16)
+    with using_registry(MetricsRegistry()) as reg:
+        op = prepare(A, device="tpu_v5e", format="csrk")
+        assert reg.get("prepare", "reorder.kept_input_order") == 1.0
+        assert reg.get("prepare", "phase.reorder.symperm_calls") is None
+        x = jnp.asarray(np.random.default_rng(8).standard_normal(A.n), jnp.float32)
+        op.apply_original(x).block_until_ready()
+        jax.profiler.start_trace(str(tmp_path))
+        op.apply_original(x).block_until_ready()
+        jax.profiler.stop_trace()
+    names = [s[0] for s in sorted(_host_spans(str(tmp_path), "repro."),
+                                  key=lambda s: (s[1], -s[2]))]
+    want = ["repro.apply_original", "repro.spmv_csrk_bucketed", "repro.pad_x",
+            "repro.combine"]
+    assert names == want + ["repro.remainder"] * bool(op.tile_buckets.remainder_nnz)
+
+
 def test_the_compilation_cache_keys_programs_with_their_region_names():
     # a program compiled under other scope names must not come back from the cache
     assert jax.config.jax_compilation_cache_include_metadata_in_key
@@ -361,7 +379,7 @@ def test_registry_timer_opens_a_host_span(tmp_path):
 def test_bandk_phase_timers_sum_within_the_reorder_phase():
     A = grid_laplacian_2d(24, 24)
     with using_registry(MetricsRegistry()) as reg:
-        prepare(A, device="tpu_v5e", format="auto")
+        prepare(A, device="tpu_v5e", format="auto", reorder="bandk")
         ms = {r["name"]: r["value"] for r in reg.records() if r["section"] == "prepare"}
     parts = ["phase.reorder.graph", "phase.reorder.coarsen", "phase.reorder.order",
              "phase.reorder.symperm"]

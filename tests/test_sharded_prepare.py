@@ -115,7 +115,7 @@ for strat in ("auto", "replicated", "allgather", "halo"):
     assert bool(jnp.all(op(x) == y_ref)), (strat, "vector")
     assert bool(jnp.all(op(X) == Y_ref)), (strat, "block")
     assert op(X).shape == (A.m, 5)
-# apply_original round-trips the Band-k permutation identically
+# apply_original matches the single-device operator's
 op = prepare(A, format="auto", mesh=mesh)
 assert bool(jnp.all(op.apply_original(x) == base.apply_original(x)))
 assert bool(jnp.all(op.apply_original(X) == base.apply_original(X)))
@@ -125,6 +125,28 @@ try:
     raise SystemExit("matmat should reject [n]")
 except ValueError:
     pass
+print('OK')
+""")
+    assert "OK" in out
+
+
+def test_sharded_apply_original_follows_the_base_perm():
+    """Natural order kept (identity perm): apply_original is the sharded call
+    itself, no gathers; a Band-k perm still round-trips as the base's does."""
+    out = run_script("""
+A = grid_laplacian_2d(40, 40)
+x = jnp.asarray(rng.standard_normal(A.n), jnp.float32)
+X = jnp.asarray(rng.standard_normal((A.n, 3)), jnp.float32)
+op = prepare(A, format="auto", mesh=mesh)
+assert op.base._identity_perm
+for v in (x, X):
+    assert bool(jnp.all(op.apply_original(v) == op(v)))
+    assert str(jax.make_jaxpr(op.apply_original)(v)) == str(jax.make_jaxpr(op.__call__)(v))
+B = A.symmetric_permute(np.random.default_rng(2).permutation(A.m))
+sb, base = prepare(B, format="auto", mesh=mesh), prepare(B, format="auto")
+assert not sb.base._identity_perm and np.array_equal(sb.perm, base.perm)
+for v in (x, X):
+    assert bool(jnp.all(sb.apply_original(v) == base.apply_original(v)))
 print('OK')
 """)
     assert "OK" in out

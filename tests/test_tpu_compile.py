@@ -30,19 +30,30 @@ from repro.kernels.spmv_sellcs import spmv_sellcs_pallas
 #: case a whole grid step has to be padded for.
 ECOLOGY1 = dict(buckets=((1, 384), (8928, 640)), R=112, W=6784, n=1_000_000)
 
-#: The same matrix as Band-k orders it on the chip's host: a narrower
-#: window, so 256-column one-hot chunks (6784 = 53·128 allows only 128).
-ECOLOGY1_CHIP = dict(ECOLOGY1, W=4864)
-
 #: At most 12 of a tile's 128-column window blocks hold one of its columns:
 #: the ``col_blocks`` table is one count column and 12 block columns.
 TABLE_WIDTH = 13
+
+#: The same matrix as Band-k orders it on the chip's host: a narrower
+#: window, so 256-column one-hot chunks (6784 = 53·128 allows only 128).
+ECOLOGY1_CHIP = dict(ECOLOGY1, W=4864, table=TABLE_WIDTH)
+
+#: ecology1 in its own grid order, which ``prepare(reorder="auto")`` keeps:
+#: three slot buckets, a 2176-column window (17 blocks, so 128-column
+#: chunks) and at most 6 listed blocks a tile.
+ECOLOGY1_NATURAL = dict(buckets=((1, 256), (17, 512), (8911, 640)), R=112, W=2176,
+                        n=1_000_000, table=7)
 
 #: HPCG's 104³ grid (1,124,864 rows, 27-point) after Band-k on a build
 #: host: its largest slot bucket at R = 56, S = 1536 and a window of 91,264
 #: columns (83,200 on the chip's host), whose x blocks at 8 tiles a step
 #: would overrun VMEM; 75 table blocks, as the chip's host has them.
-HPCG104 = dict(T=17_911, S=1536, R=56, W=91_264, n=1_124_864, table=76)
+HPCG104 = dict(T=17_911, S=1536, R=56, W=91_264, n=1_124_864, table=76, steps=1)
+
+#: The 104³ grid in its own order, which ``prepare(reorder="auto")`` keeps:
+#: a 22,016-column window, 4 tiles' x blocks a grid step, at most 11 listed
+#: blocks a tile; 19,263 of its 20,087 tiles hold 1536 slots.
+HPCG104_NATURAL = dict(T=19_263, S=1536, R=56, W=22_016, n=1_124_864, table=12, steps=4)
 
 VALUE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
@@ -147,14 +158,17 @@ def test_diahybrid_compiles_at_its_limit(one_chip, B):
     )
 
 
+@pytest.mark.parametrize("order", ["band_k", "natural"])
 @pytest.mark.parametrize("B", [1, 8])
 @pytest.mark.parametrize("vd", ["f32", "bf16", "int8"])
-def test_csrk_chunk_table_compiles_at_ecology1(one_chip, vd, B):
-    """The chip host's geometry: 256-column chunks, two 128-column blocks
-    each, so a listed block can fall in the chunk visited last."""
-    R, W = ECOLOGY1_CHIP["R"], ECOLOGY1_CHIP["W"]
-    L = (-(-ECOLOGY1_CHIP["n"] // W) + 1) * W
-    for T, S in ECOLOGY1_CHIP["buckets"]:
+def test_csrk_chunk_table_compiles_at_ecology1(one_chip, vd, B, order):
+    """Band-k's order on the chip's host: 256-column chunks, two 128-column
+    blocks each, so a listed block can fall in the chunk visited last.  The
+    grid's own order: 128-column chunks and three slot buckets."""
+    g = {"band_k": ECOLOGY1_CHIP, "natural": ECOLOGY1_NATURAL}[order]
+    R, W = g["R"], g["W"]
+    L = (-(-g["n"] // W) + 1) * W
+    for T, S in g["buckets"]:
         scale = (_spec((T, S // 128), jnp.float32, one_chip)
                  if vd == "int8" else None)
         _assert_mosaic(
@@ -163,20 +177,22 @@ def test_csrk_chunk_table_compiles_at_ecology1(one_chip, vd, B):
             _spec((T, S), jnp.int32, one_chip),
             _spec((T, S), jnp.int32, one_chip),
             _spec((T,), jnp.int32, one_chip),
-            _spec((T, TABLE_WIDTH), jnp.int32, one_chip),
+            _spec((T, g["table"]), jnp.int32, one_chip),
             _x(L, B, one_chip),
             scale,
             rows_per_tile=R, window=W, gather_chunk=512,
         )
 
 
-def test_csrk_compiles_at_hpcg104_level0(one_chip):
-    """A window this wide takes one tile's x blocks a grid step, over eight
-    sub-steps of each block of the tile streams.  The described chip does not
-    check VMEM (8 a step compiles here too), so the rule is pinned as well."""
-    g = HPCG104
+@pytest.mark.parametrize("order", ["band_k", "natural"])
+def test_csrk_compiles_at_hpcg104_level0(one_chip, order):
+    """Band-k's window is so wide that it takes one tile's x blocks a grid
+    step, over eight sub-steps of each block of the tile streams; the grid's
+    own order takes four.  The described chip does not check VMEM (8 a step
+    compiles here too), so the rule is pinned as well."""
+    g = {"band_k": HPCG104, "natural": HPCG104_NATURAL}[order]
     T, S = g["T"], g["S"]
-    assert x_tiles_per_step(S, g["W"], 3, pick_chunk(g["W"], 512)) == 1
+    assert x_tiles_per_step(S, g["W"], 3, pick_chunk(g["W"], 512)) == g["steps"]
     L = (-(-g["n"] // g["W"]) + 1) * g["W"]
     _assert_mosaic(
         spmv_csrk_tiles_pallas,
