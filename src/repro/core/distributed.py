@@ -584,8 +584,7 @@ class ShardedPreparedSpMV:
 
         Delegates to :meth:`ShardPlan.collective_bytes`: halo counts only the
         need-based edges the plan actually schedules, allgather counts the
-        full O(n) gather.  This is the quantity ``benchmarks/distributed.py``
-        records — the O(band) vs O(n) argument in numbers.
+        full O(n) gather — the O(band) vs O(n) argument in numbers.
         """
         return self.plan.collective_bytes(B, itemsize)
 

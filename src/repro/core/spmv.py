@@ -51,6 +51,7 @@ from repro.sparse import (
     tiles_from_csrk,
     tiles_from_sellcs,
 )
+from repro.sparse.quant import INT8_GROUP, dequantize_int8_grouped, quantize_int8_grouped
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.gather import WHOLE_X_MAX_COLS, pick_chunk, resolve_interpret
@@ -353,10 +354,6 @@ def _auto_value_dtype(
     ``candidates`` restricts the dtypes a backend supports (the diahybrid
     plane has no slot grouping for int8 scales, so it probes bf16 only).
     """
-    from repro.optim.compress import (
-        INT8_GROUP, dequantize_int8_grouped, quantize_int8_grouped,
-    )
-
     nnz = A.nnz
     if nnz < 4 * INT8_GROUP or (stats is not None and stats.nnz < 4 * INT8_GROUP):
         return "f32"
@@ -479,7 +476,7 @@ def prepare(
         the container in agreement).
       value_dtype: storage dtype of the kernel value stream — "f32" (exact),
         "bf16" (2 B/value), "int8" (1 B/value + one f32 scale per 128-slot
-        group, the grouped-scale idiom from :mod:`repro.optim.compress`), or
+        group, the grouped-scale idiom from :mod:`repro.sparse.quant`), or
         "auto" (probe SpMV picks the cheapest dtype within the documented
         error bounds: int8 ≤ 2.5e-2, bf16 ≤ 5e-3 relative).  Accumulation is
         always f32; indices and the COO remainder are unaffected.  The

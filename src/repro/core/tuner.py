@@ -10,8 +10,9 @@ case tables).
 We keep the paper's Volta/Ampere formulas verbatim (they are checked against
 the paper in tests) and add a TPU-v5e device model whose cases are keyed on
 the same rdensity thresholds but express 8×128 tile alignment instead of
-warp-of-32 block shapes.  The TPU (a, b) constants are produced by
-``benchmarks/tuning_model.py`` (sweep + log fit, same protocol).
+warp-of-32 block shapes.  The TPU (a, b) constants were set by hand; no
+chip sweep has fitted them yet.  A measured model can replace them at run
+time (:func:`use_device_model`, ``REPRO_DEVICE_MODEL``).
 """
 from __future__ import annotations
 
@@ -55,8 +56,8 @@ class DeviceModel:
     """Fitted ⌊a − b·ln(rdensity)⌉ model plus density-case corrections.
 
     ``gather_chunk`` is the device's preferred one-hot gather chunk width —
-    hand-set for the builtin models, measured by
-    ``benchmarks/fit_device_model.py`` for fitted ones.
+    hand-set for the builtin models, read from the model file for fitted
+    ones (:func:`load_fitted_device_model`).
     """
 
     name: str
@@ -75,17 +76,17 @@ class DeviceModel:
 
 VOLTA = DeviceModel("volta", ssrs_a=8.900, ssrs_b=1.25, srs_a=10.146, srs_b=1.50)
 AMPERE = DeviceModel("ampere", ssrs_a=9.175, ssrs_b=1.32, srs_a=20.500, srs_b=3.50)
-# TPU-v5e constants fitted by benchmarks/tuning_model.py (see EXPERIMENTS.md):
-# the sweep optimises padded-tile efficiency (useful-slot fraction × occupancy)
-# over the synthetic Table-2 suite.
+# TPU-v5e constants, set by hand to favour padded-tile efficiency (useful-slot
+# fraction × occupancy) over the synthetic Table-2 suite; no chip sweep has
+# fitted them.
 TPU_V5E = DeviceModel("tpu_v5e", ssrs_a=9.0, ssrs_b=1.10, srs_a=12.0, srs_b=1.60)
 
 DEVICES: Dict[str, DeviceModel] = {d.name: d for d in (VOLTA, AMPERE, TPU_V5E)}
 
 
 # ---------------------------------------------------------------------------
-# measured-model loading (the calibration loop closed: see
-# benchmarks/fit_device_model.py and docs/tuning.md)
+# measured-model loading (a fitted model file replaces the hand-set
+# constants; see docs/tuning.md)
 # ---------------------------------------------------------------------------
 
 #: Installed fitted model for the TPU path; None → resolve from the
@@ -97,7 +98,7 @@ _ENV_RESOLVED = False
 def load_fitted_device_model(
     path: str, name: str = "tpu_v5e"
 ) -> DeviceModel:
-    """Load fitted ``(a, b)`` constants written by benchmarks/fit_device_model.py.
+    """Load fitted ``(a, b)`` constants from a JSON model file.
 
     The file maps device name → ``{"ssrs": [a, b], "srs": [a, b],
     "gather_chunk": g}``.  A missing/unreadable file or absent device entry
@@ -240,7 +241,7 @@ def tune_tpu(rdensity: float, m: int | None = None) -> TuningParams:
         count stays near a multiple of 128 (lane count).
 
     Runs on :func:`active_tpu_model` — the hand-set :data:`TPU_V5E` constants
-    unless a fitted model (benchmarks/fit_device_model.py) was installed via
+    unless a fitted model was installed via
     :func:`use_device_model` or the ``REPRO_DEVICE_MODEL`` env var.
     """
     model = active_tpu_model()

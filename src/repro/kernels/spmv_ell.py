@@ -1,7 +1,7 @@
 """Pallas TPU kernel for ELL SpMV — the GPU-heritage baseline (paper Sec. 2.3).
 
 ELL is the format the paper cites as the historical GPU favourite; it is kept
-here as the baseline the CSR-k kernel is compared to in benchmarks/formats.py.
+here as the baseline the CSR-k kernel is compared to.
 The kernel tiles the m×kmax dense slab over rows; x is not windowed (ELL has
 no banding guarantee), so x must fit VMEM — exactly the ELL scalability
 weakness the paper describes, now visible as a VMEM constraint.

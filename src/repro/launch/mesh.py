@@ -1,7 +1,7 @@
-"""Production mesh construction.
+"""Host mesh construction.
 
-Defined as functions (never module-level constants) so importing this module
-never touches jax device state — smoke tests must keep seeing 1 device.
+Defined as a function (never a module-level constant) so importing this
+module never touches jax device state — smoke tests must keep seeing 1 device.
 """
 from __future__ import annotations
 
@@ -10,37 +10,10 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
 def make_host_mesh(model: int = 1) -> Mesh:
-    """Mesh over whatever devices exist (tests, examples, elastic rebuild)."""
+    """Mesh over whatever devices exist (tests, examples)."""
     n = len(jax.devices())
     model = max(min(model, n), 1)
     data = n // model
     devs = np.asarray(jax.devices()[: data * model]).reshape(data, model)
     return Mesh(devs, ("data", "model"))
-
-
-def batch_axes(mesh: Mesh):
-    """Axes the batch dimension shards over (pod joins DP when present)."""
-    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-
-
-def rebuild_mesh_after_failure(failed_fraction: float = 0.0) -> Mesh:
-    """Elastic rebuild: re-form the largest data×model mesh from live devices.
-
-    On a real cluster the runtime re-enumerates healthy hosts after a failure
-    (jax.distributed re-init); here we model the same policy over the local
-    device set: keep the model axis, shrink data.
-    """
-    devs = jax.devices()
-    keep = max(int(len(devs) * (1 - failed_fraction)), 1)
-    model = 1
-    data = keep // model
-    arr = np.asarray(devs[: data * model]).reshape(data, model)
-    return Mesh(arr, ("data", "model"))

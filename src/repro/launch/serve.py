@@ -1,4 +1,4 @@
-"""Serving CLI: drive the :mod:`repro.serve` SpMV engine (or the LM smoke).
+"""Serving CLI: drive the :mod:`repro.serve` SpMV engine.
 
 Default mode is a thin CLI over :class:`repro.serve.ServeEngine` — the real
 serving path the ROADMAP asked for: it registers a small matrix fleet
@@ -10,12 +10,6 @@ batching + operator cache, drains, verifies a sample against direct
 
 SpMV serving example:
   PYTHONPATH=src python -m repro.launch.serve --requests 32 --max-batch 8
-
-The pre-engine single-shot LM generation smoke (one prefill + greedy decode
-steps through the KV-cache path, timed through the registry) is kept behind
-``--arch``:
-  PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --smoke \
-      --batch 4 --prompt-len 64 --gen 32
 """
 from __future__ import annotations
 
@@ -24,16 +18,13 @@ import time
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from repro.obs import get_registry
 
 
 def _powerlaw(m: int, scale: float = 6.0, seed: int = 3):
-    """Power-law nnz/row CSR matrix — the canonical irregular workload
-    (same construction as benchmarks/format_select.py, inlined so the CLI
-    never imports the benchmark tree)."""
+    """Power-law nnz/row CSR matrix — the canonical irregular workload."""
     from repro.sparse import COOMatrix, csr_from_coo
 
     rng = np.random.default_rng(seed)
@@ -110,73 +101,10 @@ def run_spmv_serve(args) -> None:
                   f"{r['value']:.3f} {r['unit']}")
 
 
-def run_lm_smoke(args) -> None:
-    """Single-shot generation smoke: one prefill + greedy decode steps."""
-    from repro.configs.registry import get_config, get_smoke_config
-    from repro.launch.mesh import make_host_mesh
-    from repro.launch import steps as STEPS
-    from repro.models import transformer as TF
-
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if cfg.is_encdec or cfg.frontend is not None:
-        raise SystemExit(f"{args.arch}: use examples for frontend archs")
-    mesh = make_host_mesh()
-    key = jax.random.PRNGKey(0)
-    B, P, G = args.batch, args.prompt_len, args.gen
-    max_len = P + G
-
-    with mesh:
-        params = TF.init_params(key, cfg)
-        prompts = jax.random.randint(key, (B, P), 0, cfg.vocab)
-        cache = TF.init_cache(cfg, B, max_len)
-        decode_step = jax.jit(STEPS.make_decode_step(cfg, mesh), donate_argnums=(1,))
-
-        reg = get_registry()
-        # prefill through the cache path (writes K/V for the prompt)
-        t0 = time.time()
-        with reg.timer("serve", "prefill"):
-            logits, cache, _ = TF.forward(
-                params, prompts, cfg, cache=cache, cache_index=jnp.zeros((), jnp.int32)
-            )
-            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            jax.block_until_ready(tok)
-        t_prefill = time.time() - t0
-
-        out = [tok]
-        t0 = time.time()
-        for i in range(G - 1):
-            t_step = time.perf_counter()
-            logits, cache = decode_step(
-                params, cache, tok, jnp.asarray(P + i, jnp.int32)
-            )
-            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            if reg.enabled:
-                # per-step timing needs a sync point; only pay it when
-                # telemetry is on (disabled runs keep async dispatch)
-                jax.block_until_ready(tok)
-                reg.observe("serve", "decode_step_ms",
-                            (time.perf_counter() - t_step) * 1e3, unit="ms")
-            out.append(tok)
-        t_decode = time.time() - t0
-        reg.gauge("serve", "tokens_per_s",
-                  (G - 1) * B / max(t_decode, 1e-9), unit="scalar")
-
-    gen = jnp.concatenate(out, axis=1)
-    print(f"prefill {B}x{P}: {t_prefill*1e3:.1f} ms")
-    print(f"decode {G-1} steps: {t_decode*1e3:.1f} ms "
-          f"({(G-1)*B/max(t_decode,1e-9):.1f} tok/s)")
-    print("sample tokens:", gen[0, :16].tolist())
-    for r in get_registry().records():
-        if r["section"] == "serve":
-            print(f"# obs {r['section']}.{r['name']} = {r['value']:.3f} {r['unit']}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(
-        description="SpMV serving engine CLI (default) or LM generation "
-                    "smoke (--arch). See docs/serving.md.",
+        description="SpMV serving engine CLI. See docs/serving.md.",
     )
-    # SpMV serving mode
     ap.add_argument("--requests", type=int, default=32,
                     help="number of requests to replay through the engine")
     ap.add_argument("--scale", type=int, default=576,
@@ -188,21 +116,11 @@ def main() -> None:
     ap.add_argument("--cache-mb", type=float, default=0.0,
                     help="operator-cache byte budget in MiB (0 = unbounded)")
     ap.add_argument("--seed", type=int, default=0)
-    # LM smoke mode (pre-engine harness, kept working)
-    ap.add_argument("--arch", default=None,
-                    help="run the single-shot LM generation smoke instead")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=64)
-    ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
     from repro.util.platform import configure_compile_cache
 
     configure_compile_cache()
-    if args.arch is not None:
-        run_lm_smoke(args)
-    else:
-        run_spmv_serve(args)
+    run_spmv_serve(args)
 
 
 if __name__ == "__main__":

@@ -1,15 +1,12 @@
 """repro.obs — telemetry for the sparse stack (docs/observability.md).
 
-Three pieces, one import site:
+Two pieces, one import site:
 
 * :mod:`repro.obs.registry` — process-global :class:`MetricsRegistry` of
   counters / gauges / timers / series, exported in the benchmark record
   schema; tracer-safe and a strict no-op when disabled.
 * :mod:`repro.obs.trace` — :func:`annotate` / :func:`annotated` profiler
   scopes (``jax.named_scope`` + ``jax.profiler.TraceAnnotation``).
-* :mod:`repro.obs.export` — metadata stamping and the
-  ``{"meta", "records"}`` JSON file format the trajectory aggregator and
-  the perf-regression gate consume.
 
 Instrumentation contract: observing never changes a computed value
 (``tests/test_obs.py`` pins kernel and solver outputs bit-for-bit with
@@ -27,25 +24,17 @@ from repro.obs.registry import (
     using_registry,
 )
 from repro.obs.trace import annotate, annotated
-from repro.obs.export import (
-    collect_metadata,
-    read_records,
-    write_records,
-)
 
 __all__ = [
     "MetricsRegistry",
     "SERIES_CAP",
     "annotate",
     "annotated",
-    "collect_metadata",
     "concrete",
     "disable",
     "enable",
     "enabled",
     "get_registry",
-    "read_records",
     "set_registry",
     "using_registry",
-    "write_records",
 ]

@@ -4,8 +4,8 @@ One process-global :class:`MetricsRegistry` (swap it with
 :func:`set_registry` / :func:`using_registry`) accumulates everything the
 instrumented layers emit — ``prepare()`` phase timings, solver residual
 series, sharding decisions — and exports them as
-the same ``{"section", "name", "value", "unit"}`` records the benchmark
-harness already archives, so telemetry and perf tracking share one schema.
+``{"section", "name", "value", "unit"}`` records, which the chip
+benchmark's harness (``bench/harness.py``) reads.
 
 Design constraints, in order:
 

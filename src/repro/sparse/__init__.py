@@ -5,6 +5,7 @@ everything here for back-compat).  Layout:
 
 * :mod:`repro.sparse.coo` / :mod:`repro.sparse.csr` — interchange formats
 * :mod:`repro.sparse.csrk` — the paper's CSR-k + its TPU tile view
+* :mod:`repro.sparse.quant` — grouped-scale int8 value streams
 * :mod:`repro.sparse.sellcs` — SELL-C-σ for irregular matrices
 * :mod:`repro.sparse.segsum` — speculative segmented-sum CSR (power-law path)
 * :mod:`repro.sparse.diahybrid` — DIA + CSR remainder (stencil path)

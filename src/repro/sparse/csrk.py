@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sparse.csr import CSRMatrix
+from repro.sparse.quant import INT8_GROUP, quantize_int8_grouped
 
 Array = Any
 
@@ -30,10 +31,6 @@ _INT = jnp.int32
 #: Bytes per stored value, by tile-view value dtype.  Mirrors the accounting
 #: in ``repro.core.tuner.tile_bytes_model`` (value + 4B col + 4B row indices).
 VALUE_BYTES = {"f32": 4, "bf16": 2, "int8": 1}
-
-#: Slots per int8 scale group (= the TPU lane count; slot counts are always
-#: padded to multiples of 128, so groups tile the slot axis exactly).
-INT8_GROUP = 128
 
 
 @jax.tree_util.register_pytree_node_class
@@ -190,7 +187,7 @@ class CSRkTiles:
     ``"bf16"`` (half the value bytes, exact codes for the suite's small-int
     stencil weights), or ``"int8"`` with per-group symmetric scales in
     ``val_scale`` (one f32 scale per :data:`INT8_GROUP` slots — the GPTQ-style
-    grouped-scale idiom from :mod:`repro.optim.compress`).  Kernels and
+    grouped-scale idiom from :mod:`repro.sparse.quant`).  Kernels and
     oracles dequantize on load and accumulate in f32 either way; the COO
     remainder always stays f32.  ``tile_nnz`` records each tile's real
     (in-window) entry count so :func:`bucket_tiles` can compact slots without
@@ -301,7 +298,7 @@ def _pack_values(tvals: np.ndarray, value_dtype: str):
     """Convert the freshly built f32 tile values to ``value_dtype``.
 
     Returns ``(vals_device, val_scale_device_or_None)``.  bf16 is a plain
-    cast; int8 uses the grouped-scale idiom from :mod:`repro.optim.compress`
+    cast; int8 uses the grouped-scale idiom from :mod:`repro.sparse.quant`
     (one f32 scale per :data:`INT8_GROUP` slots along the slot axis).
     """
     if value_dtype == "f32":
@@ -309,8 +306,6 @@ def _pack_values(tvals: np.ndarray, value_dtype: str):
     if value_dtype == "bf16":
         return jnp.asarray(tvals).astype(jnp.bfloat16), None
     if value_dtype == "int8":
-        from repro.optim.compress import quantize_int8_grouped
-
         q, scales = quantize_int8_grouped(tvals, group=INT8_GROUP)
         return jnp.asarray(q), jnp.asarray(scales)
     raise ValueError(

@@ -52,7 +52,7 @@ class FormatSpec:
 
     ``matches(stats, device)`` must be O(1) — a predicate over the summary
     statistics only.  ``selectable=False`` keeps a format addressable by name
-    (``get_format`` for benchmarks/tooling) without the auto selector ever
+    (``get_format`` for baselines and tests) without the auto selector ever
     routing to it.
     """
 

@@ -4,14 +4,11 @@ Covers, in order: registry counter/gauge/timer/series semantics; the
 disabled registry being a true no-op; tracer safety under ``jit`` (nothing
 abstract is ever stored); ``prepare()`` phase timings and structural gauges
 on both the csrk and sellcs routes; the sharded operator's decision
-counters; solver residual series; metadata stamping; the trajectory
-aggregator; the regression gate's exit codes; and the contract that
-underwrites all of it — enabling telemetry changes no computed bit.
+counters; solver residual series; the profiler spans on the trace's clock;
+and the contract that underwrites all of it — enabling telemetry changes
+no computed bit.
 """
-import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -26,8 +23,6 @@ from repro.configs.spmv_suite import grid_laplacian_2d
 from repro.core.solvers import block_cg, cg
 from repro.core.spmv import prepare
 from repro.sparse import CSRMatrix
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def powerlaw_csr(rng, m=128, scale=4.0):
@@ -388,86 +383,3 @@ def test_bandk_phase_timers_sum_within_the_reorder_phase():
     assert ms["phase.reorder.coarsen_calls"] == 2          # k = 3: two levels
     assert ms["phase.reorder.order_calls"] == 3            # every level ordered
     assert 0 < sum(ms[f"{p}_ms"] for p in parts) <= ms["phase.reorder_ms"]
-
-
-# --- metadata / export / trajectory / gate -----------------------------------
-
-
-def test_collect_metadata_has_identity_keys():
-    meta = obs.collect_metadata()
-    for key in ("git_sha", "timestamp", "jax_version", "backend",
-                "device_kind", "device_count", "python_version"):
-        assert meta.get(key) not in (None, ""), key
-    assert meta["device_count"] >= 1
-    assert "T" in meta["timestamp"]  # ISO-8601
-
-
-def test_write_read_records_roundtrip_and_legacy(tmp_path):
-    recs = [{"section": "s", "name": "n", "value": 1.0, "unit": "us"}]
-    p = tmp_path / "bench.json"
-    obs.write_records(str(p), recs)
-    meta, out = obs.read_records(str(p))
-    assert out == recs and meta["git_sha"]
-    legacy = tmp_path / "legacy.json"
-    legacy.write_text(json.dumps(recs))
-    meta, out = obs.read_records(str(legacy))
-    assert out == recs and meta == {}
-
-
-def _bench_file(tmp_path, name, sha, ts, value_us):
-    payload = {
-        "meta": {"git_sha": sha, "timestamp": ts, "jax_version": "0.4.37",
-                 "backend": "cpu", "device_kind": "cpu", "device_count": 1},
-        "records": [
-            {"section": "formats", "name": "m.kernel_us",
-             "value": value_us, "unit": "us"},
-            {"section": "formats", "name": "m.gflops",
-             "value": 1e5 / value_us, "unit": "gflop/s"},
-        ],
-    }
-    path = tmp_path / name
-    path.write_text(json.dumps(payload))
-    return str(path)
-
-
-def test_trajectory_orders_points_and_renders_markdown(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    try:
-        import report
-    finally:
-        sys.path.pop(0)
-    newer = _bench_file(tmp_path, "BENCH_bbb.json", "b" * 40,
-                        "2026-02-02T00:00:00+00:00", 900.0)
-    older = _bench_file(tmp_path, "BENCH_aaa.json", "a" * 40,
-                        "2026-01-01T00:00:00+00:00", 1000.0)
-    traj = report.build_trajectory([newer, older])
-    assert [p["git_sha"][0] for p in traj["points"]] == ["a", "b"]
-    assert traj["points"][0]["summary"]["formats.mean_us"] == 1000.0
-    md = report.trajectory_markdown(traj)
-    assert "aaaaaaaa" in md and "bbbbbbbb" in md and "formats.mean_us" in md
-
-
-def test_regression_gate_exit_codes(tmp_path):
-    gate = os.path.join(REPO, "benchmarks", "check_regression.py")
-    base = _bench_file(tmp_path, "base.json", "a" * 40,
-                       "2026-01-01T00:00:00+00:00", 1000.0)
-    same = _bench_file(tmp_path, "same.json", "b" * 40,
-                       "2026-01-02T00:00:00+00:00", 1010.0)
-    slow = _bench_file(tmp_path, "slow.json", "c" * 40,
-                       "2026-01-03T00:00:00+00:00", 3000.0)
-
-    def run(new, baseline):
-        return subprocess.run(
-            [sys.executable, gate, new, baseline, "--tolerance", "0.5",
-             "--min-us", "100"],
-            capture_output=True, text=True, timeout=60,
-        )
-
-    ok = run(same, base)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    bad = run(slow, base)
-    assert bad.returncode == 1, bad.stdout + bad.stderr
-    assert "REGRESSION" in bad.stdout
-    first = run(same, str(tmp_path / "missing.json"))
-    assert first.returncode == 0  # warn-only on first run
-    assert "no baseline" in first.stdout

@@ -14,8 +14,8 @@ from repro.core.formats import (CSRMatrix, build_csrk, sellcs_from_csr,
                                 tiles_from_csrk, tiles_from_sellcs)
 from repro.core.spmv import prepare
 from repro.kernels import ops, ref
-from repro.optim.compress import (INT8_GROUP, dequantize_int8_grouped,
-                                  quantize_int8_grouped)
+from repro.sparse.quant import (INT8_GROUP, dequantize_int8_grouped,
+                                quantize_int8_grouped)
 
 from _close import assert_reordered_sum_close
 
